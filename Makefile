@@ -14,8 +14,8 @@ bench:
 	$(PY) -m benchmarks.run
 
 # minutes-scale benchmark pass (CI): tiny substrate, then assert every
-# JSON artifact parses and BENCH_kernels.json carries the pipelined /
-# packed-sort / chunk-x-blk_l sweep schema
+# JSON artifact parses and BENCH_kernels.json carries the packed-sort /
+# chunk-x-blk_l sweep schema
 bench-smoke:
 	$(PY) -m benchmarks.run --smoke
 	$(PY) -c "import json; \
@@ -24,14 +24,12 @@ bench-smoke:
 	  d = json.load(open('artifacts/BENCH_kernels.json')); \
 	  assert {'rows', 'fused_sweep', 'sort', 'backend'} <= d.keys(); \
 	  assert d['fused_sweep'], 'empty fused sweep'; \
-	  assert all({'chunk', 'blk_l', 'us', 'pipelined', 'delta'} \
+	  assert all({'chunk', 'blk_l', 'us', 'delta'} \
 	             <= r.keys() for r in d['fused_sweep']); \
 	  assert any(r['delta'] for r in d['fused_sweep']), \
 	         'no in-kernel-delta row'; \
 	  s = d['sort']; \
 	  assert s['packed_us'] > 0 and s['tagged_us'] > 0; \
-	  assert any(r['us'] is None for r in d['rows']) \
-	         or d['pipelined_available'], 'pipelined row missing'; \
 	  sv = json.load(open('artifacts/BENCH_serving.json')); \
 	  gap = sv['live_stream']['recall_gap']; \
 	  assert gap <= 0.01, f'live-stream recall gap {gap} > 1%'; \

@@ -8,13 +8,11 @@ the CPU baseline the TPU kernels replace, the ``*_ops`` rows catch
 dispatch-path regressions. Printed as name,us_per_call,max_err CSV.
 
 ``main`` returns the BENCH_kernels.json artifact: the legacy ``rows``
-plus a ``fused_sweep`` (chunk × blk_l, pipelined/unpipelined, with and
-without the in-kernel delta stream), a ``sort`` section timing the
-packed (score,id) network against the legacy three-lane tagged
-network, and backend metadata.  ``pltpu.emit_pipeline`` asserts a real
-TPU at trace time, so on CPU the pipelined variants are recorded as
-pending (``us: null``) — the speedup claim is documented as pending a
-TPU run, not measured in interpret mode.
+plus a ``fused_sweep`` (chunk × blk_l, with and without the in-kernel
+delta stream), a ``sort`` section timing the packed (score,id) network
+against the legacy three-lane tagged network, and backend metadata.
+The fused kernel runs one body on both backends (grid-streamed tiles),
+so every row is measured on whatever backend runs the bench.
 """
 from __future__ import annotations
 
@@ -156,7 +154,7 @@ def main(smoke: bool = False) -> Dict:
               float(jnp.max(jnp.abs(o_ops[2] - o_ref[2]))))
     add("ivf_scan_merge_ref_xla", _time(jfused, reps=reps), err)
 
-    def sweep_chunk(chunk: int, blk_l: int = 64) -> float:
+    def sweep_chunk(chunk: int, blk_l: int = 128) -> float:
         """us for the full n_pr probes issued as n_pr/chunk dispatches."""
         offs = all_offs.reshape(B, n_pr // chunk, chunk)
         szs = jnp.full((B, chunk), lp - 6, jnp.int32)
@@ -175,18 +173,13 @@ def main(smoke: bool = False) -> Dict:
     for chunk in ([4] if smoke else [1, 2, 4, 8]):
         add(f"ivf_scan_merge_ops_c{chunk}", sweep_chunk(chunk), err)
 
-    # chunk × blk_l sweep: dispatch granularity vs tile height.  The
-    # ops wrapper picks the tile streaming mode per backend: pipelined
-    # (double-buffered emit_pipeline) on TPU, the unrolled interpret
-    # fallback on CPU — so the pipelined variant is only measurable on
-    # real hardware and is recorded as pending elsewhere.
-    on_tpu = jax.default_backend() == "tpu"
+    # chunk × blk_l sweep: dispatch granularity vs tile height (tiles
+    # are written at blk_l-multiple lane offsets, so blk_l >= 128 on TPU)
     fused_sweep = []
     for chunk in ([4] if smoke else [2, 4, 8]):
-        for blk_l in ([64] if smoke else [64, 128, 256]):
+        for blk_l in ([128] if smoke else [128, 256]):
             fused_sweep.append({
-                "chunk": chunk, "blk_l": blk_l,
-                "pipelined": on_tpu, "delta": False,
+                "chunk": chunk, "blk_l": blk_l, "delta": False,
                 "us": sweep_chunk(chunk, blk_l), "err": err})
 
     # in-kernel delta stream: same probes plus a 256-entry buffer
@@ -205,16 +198,12 @@ def main(smoke: bool = False) -> Dict:
             k=kk, list_pad=lp, chunk=4)
 
     fused_sweep.append({
-        "chunk": 4, "blk_l": 64, "pipelined": on_tpu, "delta": True,
+        "chunk": 4, "blk_l": 128, "delta": True,
         "us": _time(run_delta, reps=reps), "err": err})
     for row in fused_sweep:
-        mode = "pipelined" if row["pipelined"] else "unpipelined"
         tag = "_delta" if row["delta"] else ""
-        add(f"fused_{mode}_c{row['chunk']}_blk{row['blk_l']}{tag}",
+        add(f"fused_c{row['chunk']}_blk{row['blk_l']}{tag}",
             row["us"], row["err"])
-    if not on_tpu:
-        # emit_pipeline cannot trace off-TPU: document, don't fake
-        add("fused_pipelined_c4_blk64", None, None)
 
     # delta scan (live-mutation buffer brute force)
     dvecs = r.normal(r.PRNGKey(13), (1024, 64))
@@ -234,16 +223,12 @@ def main(smoke: bool = False) -> Dict:
     # the single err check above already exercises the ops path
 
     for row in rows:
-        us = "pending" if row["us"] is None else f"{row['us']:.1f}"
-        err = "" if row["err"] is None else f"{row['err']:.2e}"
-        print(f"{row['name']},{us},{err}")
+        print(f"{row['name']},{row['us']:.1f},{row['err']:.2e}")
     return {
         "rows": rows,
         "fused_sweep": fused_sweep,
         "sort": _sort_section(reps, smoke),
         "backend": jax.default_backend(),
-        "pipelined_available": on_tpu,
-        "tpu_speedup": "pending TPU run" if not on_tpu else None,
     }
 
 

@@ -32,6 +32,8 @@ def _write(name: str, payload) -> None:
 def main() -> None:
     full = "--full" in sys.argv
     smoke = "--smoke" in sys.argv
+    from repro.launch import compile_cache
+    compile_cache.enable()
     t0 = time.time()
     print("=" * 72)
     print("## Kernel micro-benchmarks (name,us_per_call,max_err)")

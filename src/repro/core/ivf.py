@@ -25,6 +25,22 @@ from repro.core import kmeans as km
 from repro.core.policies import Policy, PolicyDecision, policy_step
 from repro.core.features import FeatureExtras
 
+#: Matmul precision of every XLA scoring path.  On a TPU the default is
+#: one bf16 pass; the fused kernel scores in f32, so the reference pins
+#: f32 too.  On the CPU both are exact f32 already.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def centroid_sims(queries: jnp.ndarray, centroids: jnp.ndarray
+                  ) -> jnp.ndarray:
+    """(B, d) x (C, d) -> (B, C) inner products, the probe-order key."""
+    return jnp.matmul(queries, centroids.T, precision=HIGHEST)
+
+
+def tile_scores(tiles: jnp.ndarray, queries: jnp.ndarray) -> jnp.ndarray:
+    """(B, L, d) probed tiles x (B, d) queries -> (B, L) scores."""
+    return jnp.einsum("bld,bd->bl", tiles, queries, precision=HIGHEST)
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
@@ -70,7 +86,7 @@ class DeltaView(NamedTuple):
     assign: jnp.ndarray   # (cap,) int32 assigned cluster, -1 empty
 
 
-def validate_alignment(index: IVFIndex, *, blk_l: int = 64) -> None:
+def validate_alignment(index: IVFIndex, *, blk_l: int = 128) -> None:
     """Eagerly enforce the fused-kernel layout contract.
 
     The Pallas scan kernels stream ``(blk_l, d)`` tiles addressed by
@@ -103,7 +119,7 @@ def validate_alignment(index: IVFIndex, *, blk_l: int = 64) -> None:
 
 def build_index(docs: np.ndarray, n_clusters: int, *, list_pad: int = 256,
                 n_iters: int = 10, seed: int = 0,
-                align: int = 64) -> IVFIndex:
+                align: int = 128) -> IVFIndex:
     """k-means -> oversize split -> cluster-major re-layout.
 
     ``align``: every inverted list starts at a multiple of ``align``
@@ -236,7 +252,7 @@ def search(index: IVFIndex, queries: jnp.ndarray, policy: Policy, *,
            delta: Optional[DeltaView] = None,
            use_scan_kernel: bool = False, use_topk_kernel: bool = False,
            use_fused_kernel: bool = False, chunk: int = 1,
-           blk_l: int = 64) -> SearchResult:
+           blk_l: int = 128) -> SearchResult:
     """Batched adaptive A-kNN: probe clusters in similarity order with
     per-query early exit.
 
@@ -291,7 +307,7 @@ def _search(index: IVFIndex, queries: jnp.ndarray, policy: Policy,
     # phi1 (vs RS_1) only feeds the learned-policy feature matrix
     needs_phi1 = policy.use_classifier or policy.use_reg
 
-    csims = queries @ index.centroids.T                       # (B, C)
+    csims = centroid_sims(queries, index.centroids)           # (B, C)
     rank_sims, cluster_rank = jax.lax.top_k(csims, n_rank)    # (B, N)
 
     if delta is not None and not use_fused_kernel:
@@ -323,7 +339,7 @@ def _search(index: IVFIndex, queries: jnp.ndarray, policy: Policy,
             ids = jnp.where(mask, ids, -1)
             return jnp.where(ids >= 0, sc, -jnp.inf), ids
         tiles, ids, mask = _probe_tiles(index, cids)
-        sc = jnp.einsum("bld,bd->bl", tiles, queries)
+        sc = tile_scores(tiles, queries)
         return jnp.where(mask, sc, -jnp.inf), ids
 
     init = SearchState(
@@ -451,14 +467,13 @@ def extract_features(index: IVFIndex, queries: jnp.ndarray, *, tau: int,
     """
     from repro.core.features import FeatureExtras as FE, feature_matrix
     B = queries.shape[0]
-    csims = queries @ index.centroids.T
+    csims = centroid_sims(queries, index.centroids)
     rank_sims, cluster_rank = jax.lax.top_k(csims, min(tau, index.n_clusters))
 
     def step(carry, h):
         scores, ids, rs1, phi_h, phi1_h = carry
         tiles, tids, mask = _probe_tiles(index, cluster_rank[:, h])
-        sc = jnp.where(mask, jnp.einsum("bld,bd->bl", tiles, queries),
-                       -jnp.inf)
+        sc = jnp.where(mask, tile_scores(tiles, queries), -jnp.inf)
         ns, ni = _merge_topk(scores, ids, sc, tids, k)
         phi = intersection_pct(ids, ni)
         rs1 = jnp.where(h == 0, ni, rs1)
@@ -485,9 +500,52 @@ def extract_features(index: IVFIndex, queries: jnp.ndarray, *, tau: int,
 def brute_force(docs: jnp.ndarray, queries: jnp.ndarray, k: int
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Exact kNN oracle (id space = row index)."""
-    sims = queries @ docs.T
+    sims = jnp.matmul(queries, docs.T, precision=HIGHEST)
     s, i = jax.lax.top_k(sims, k)
     return s, i.astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block"))
+def _exact_block(index: IVFIndex, queries: jnp.ndarray, *, k: int,
+                 block: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    n = index.docs.shape[0]
+    block = min(block, n)
+    b = queries.shape[0]
+
+    def body(j, carry):
+        lo = j * block
+        # the last block ends at row n and overlaps the one before it;
+        # rows below ``lo`` were already scored there
+        start = jnp.minimum(lo, n - block)
+        docs = jax.lax.dynamic_slice_in_dim(index.docs, start, block)
+        ids = jax.lax.dynamic_slice_in_dim(index.doc_ids, start, block)
+        rows = start + jnp.arange(block)
+        sc = jnp.matmul(queries, docs.T, precision=HIGHEST)
+        sc = jnp.where(((ids >= 0) & (rows >= lo))[None, :], sc, -jnp.inf)
+        bs, bi = jax.lax.top_k(sc, k)
+        return _merge_topk(*carry, bs, jnp.take(ids, bi), k)
+
+    init = (jnp.full((b, k), -jnp.inf, jnp.float32),
+            jnp.full((b, k), -1, jnp.int32))
+    return jax.lax.fori_loop(0, -(-n // block), body, init)
+
+
+def exact_topk(index: IVFIndex, queries: jnp.ndarray, k: int, *,
+               q_block: int = 256, block: int = 65536
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """Exact kNN oracle over the index's own device docs.
+
+    Blocked over queries and over doc rows; returns external doc ids
+    (rows mapped through ``doc_ids``, padding and tombstones skipped),
+    so it needs no second copy of the corpus and no (B, n) score
+    matrix."""
+    out_s, out_i = [], []
+    for lo in range(0, queries.shape[0], q_block):
+        s, i = _exact_block(index, jnp.asarray(queries[lo: lo + q_block]),
+                            k=k, block=block)
+        out_s.append(np.asarray(s))
+        out_i.append(np.asarray(i))
+    return np.concatenate(out_s), np.concatenate(out_i)
 
 
 def probe_trace(index: IVFIndex, queries: jnp.ndarray, n_probe: int, k: int
@@ -496,15 +554,14 @@ def probe_trace(index: IVFIndex, queries: jnp.ndarray, n_probe: int, k: int
     ids after every probe h=1..N. Used for C(q) labels, Figure 1 and
     policy oracles. Returns (ids_traj (N,B,k), phi (N-1,B))."""
     B = queries.shape[0]
-    csims = queries @ index.centroids.T
+    csims = centroid_sims(queries, index.centroids)
     _, cluster_rank = jax.lax.top_k(csims, min(n_probe, index.n_clusters))
 
     def step(carry, h):
         scores, ids = carry
         cids = cluster_rank[:, h]
         tiles, tids, mask = _probe_tiles(index, cids)
-        sc = jnp.einsum("bld,bd->bl", tiles, queries)
-        sc = jnp.where(mask, sc, -jnp.inf)
+        sc = jnp.where(mask, tile_scores(tiles, queries), -jnp.inf)
         ns, ni = _merge_topk(scores, ids, sc, tids, k)
         return (ns, ni), ni
 

@@ -18,29 +18,31 @@ import numpy as np
 
 def _assign_block(x: jnp.ndarray, centroids: jnp.ndarray,
                   block: int = 4096) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Nearest-centroid assignment by inner product, blocked over rows."""
+    """Nearest-centroid assignment by inner product, blocked over rows.
+
+    A ragged tail is covered by one last block that ends at row n and
+    overlaps the one before it (overlapping rows are recomputed to the
+    same values), so the corpus is never padded or copied."""
     n = x.shape[0]
     block = min(block, n)
     c_sq = jnp.sum(centroids * centroids, axis=1)  # (C,)
-    n_pad = ((n + block - 1) // block) * block
-    xp = jnp.pad(x, ((0, n_pad - n), (0, 0))) if n_pad != n else x
 
     def body(i, carry):
         assign, best = carry
-        xb = jax.lax.dynamic_slice_in_dim(xp, i * block, block, axis=0)
+        start = jnp.minimum(i * block, n - block)
+        xb = jax.lax.dynamic_slice_in_dim(x, start, block, axis=0)
         # squared L2 = |x|^2 - 2 x.c + |c|^2 ; |x|^2 constant per row
         sims = xb @ centroids.T - 0.5 * c_sq[None, :]
         a = jnp.argmax(sims, axis=1).astype(jnp.int32)
         s = jnp.max(sims, axis=1)
-        assign = jax.lax.dynamic_update_slice_in_dim(assign, a, i * block, 0)
-        best = jax.lax.dynamic_update_slice_in_dim(best, s, i * block, 0)
+        assign = jax.lax.dynamic_update_slice_in_dim(assign, a, start, 0)
+        best = jax.lax.dynamic_update_slice_in_dim(best, s, start, 0)
         return assign, best
 
-    assign = jnp.zeros((n_pad,), jnp.int32)
-    best = jnp.zeros((n_pad,), x.dtype)
-    assign, best = jax.lax.fori_loop(0, n_pad // block, body,
-                                     (assign, best), unroll=False)
-    return assign[:n], best[:n]
+    assign = jnp.zeros((n,), jnp.int32)
+    best = jnp.zeros((n,), x.dtype)
+    return jax.lax.fori_loop(0, -(-n // block), body, (assign, best),
+                             unroll=False)
 
 
 @functools.partial(jax.jit, static_argnames=("n_clusters", "n_iters", "block"))
@@ -144,11 +146,15 @@ def split_oversized(x: np.ndarray, centroids: np.ndarray, assign: np.ndarray,
     rng = np.random.default_rng(seed)
     centroids = list(np.asarray(centroids))
     assign = np.asarray(assign).copy()
-    queue = [c for c in range(len(centroids))
-             if int((assign == c).sum()) > max_size]
+    # member rows of every oversized cluster, ascending, from one sort
+    sizes = np.bincount(assign, minlength=len(centroids))
+    starts = np.cumsum(sizes) - sizes
+    order = np.argsort(assign, kind="stable")
+    queue = [int(c) for c in np.nonzero(sizes > max_size)[0]]
+    members_of = {c: order[starts[c]: starts[c] + sizes[c]] for c in queue}
     while queue:
         c = queue.pop()
-        members = np.nonzero(assign == c)[0]
+        members = members_of[c]
         if members.size <= max_size:
             continue
         pts = x[members]
@@ -167,7 +173,8 @@ def split_oversized(x: np.ndarray, centroids: np.ndarray, assign: np.ndarray,
         centroids[c] = seeds[0]
         centroids.append(seeds[1])
         assign[members[m1]] = new_id
+        members_of[c], members_of[new_id] = members[~m1], members[m1]
         for cc in (c, new_id):
-            if int((assign == cc).sum()) > max_size:
+            if members_of[cc].size > max_size:
                 queue.append(cc)
     return np.stack(centroids).astype(np.float32), assign

@@ -23,8 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ivf import (DeltaView, IVFIndex, _merge_topk, _probe_tiles,
-                            _scrub_dead, intersection_pct,
-                            validate_alignment)
+                            _scrub_dead, centroid_sims, intersection_pct,
+                            tile_scores, validate_alignment)
 from repro.core.policies import (RUNG_CAP, RUNG_FORCE, RUNG_NONE,
                                  RUNG_TIGHTEN, DegradationLadder)
 
@@ -55,14 +55,15 @@ def _empty_state(w: int, d: int, n: int, k: int) -> LaneState:
 @functools.partial(jax.jit, static_argnames=("n_probe",))
 def _admit(state: LaneState, centroids: jnp.ndarray, new_q: jnp.ndarray,
            new_qid: jnp.ndarray, n_probe: int) -> LaneState:
-    """Fill empty lanes with up to len(new_q) queries (vectorised)."""
-    w = state.active.shape[0]
+    """Fill empty lanes with the queries of ``new_q`` whose ``new_qid``
+    is not -1 (vectorised).  Callers pad both to a fixed row count, so
+    one compiled program admits batches of every size."""
     free = ~state.active                                  # (W,)
     # slot j of new_q goes to the j-th free lane
     free_rank = jnp.cumsum(free) - 1                      # rank among free
-    take = free & (free_rank < new_q.shape[0])
+    take = free & (free_rank < jnp.sum(new_qid >= 0))
     src = jnp.clip(free_rank, 0, new_q.shape[0] - 1)
-    csims = new_q @ centroids.T
+    csims = centroid_sims(new_q, centroids)
     _, rank = jax.lax.top_k(csims, n_probe)
     def fill(old, new_full, extra_dims):
         newv = jnp.take(new_full, src, axis=0)
@@ -184,7 +185,7 @@ def _advance(index: IVFIndex, state: LaneState,
         hv = jnp.minimum(st.h, n_probe - 1)
         cids = jnp.take_along_axis(st.cluster_rank, hv[:, None], 1)[:, 0]
         tiles, ids, mask = _probe_tiles(index, cids)
-        sc = jnp.einsum("bld,bd->bl", tiles, st.qvec)
+        sc = tile_scores(tiles, st.qvec)
         sc = jnp.where(mask, sc, -jnp.inf)
         if dview is not None:
             gate = d_valid & (dview.assign[None, :] == cids[:, None])
@@ -434,14 +435,16 @@ class WaveScheduler:
                         next_q = min(nq, next_q + room)
                     else:
                         batch = queries[next_q: next_q + room]
-                        ids = np.arange(next_q,
-                                        next_q + batch.shape[0],
-                                        dtype=np.int32)
+                        m = batch.shape[0]
+                        qpad = np.zeros((self.w, d), np.float32)
+                        qpad[:m] = batch
+                        ids = np.full(self.w, -1, np.int32)
+                        ids[:m] = np.arange(next_q, next_q + m)
                         before = active
                         state = _admit(state, self._centroids(),
-                                       jnp.asarray(batch),
+                                       jnp.asarray(qpad),
                                        jnp.asarray(ids), self.n)
-                        next_q += batch.shape[0]
+                        next_q += m
                         newly = np.asarray(state.active) & ~before
                         lane_admit[newly] = now
             active = np.asarray(state.active)

@@ -39,7 +39,7 @@ from repro.index.wal import OP_ADD, OP_DELETE, OP_MERGE
 
 
 def relayout(vecs: np.ndarray, ids: np.ndarray, assign: np.ndarray,
-             centroids, *, list_pad: int, align: int = 64,
+             centroids, *, list_pad: int, align: int = 128,
              round_total_to: Optional[int] = None) -> IVFIndex:
     """Cluster-major re-layout of an already-assigned corpus.
 
@@ -52,6 +52,15 @@ def relayout(vecs: np.ndarray, ids: np.ndarray, assign: np.ndarray,
     total row count up to a multiple, so repeated merges reuse compiled
     search executables instead of re-tracing per merge.
     """
+    return _upload(_layout(vecs, ids, assign, centroids,
+                           list_pad=list_pad, align=align,
+                           round_total_to=round_total_to), list_pad)
+
+
+def _layout(vecs, ids, assign, centroids, *, list_pad: int, align: int,
+            round_total_to: Optional[int]) -> Tuple[np.ndarray, ...]:
+    """Host half of :func:`relayout`: (centroids, docs, doc_ids,
+    offsets, sizes) as numpy arrays."""
     if align <= 0:
         raise ValueError(f"align must be positive, got {align}")
     if list_pad % align:
@@ -85,9 +94,11 @@ def relayout(vecs: np.ndarray, ids: np.ndarray, assign: np.ndarray,
         sorted_docs[offsets[cid]: offsets[cid] + sz] = vecs[sel]
         sorted_ids[offsets[cid]: offsets[cid] + sz] = ids[sel]
         pos += sz
-    return IVFIndex(jnp.asarray(centroids_np), jnp.asarray(sorted_docs),
-                    jnp.asarray(sorted_ids), jnp.asarray(offsets),
-                    jnp.asarray(sizes), list_pad)
+    return centroids_np, sorted_docs, sorted_ids, offsets, sizes
+
+
+def _upload(layout: Tuple[np.ndarray, ...], list_pad: int) -> IVFIndex:
+    return IVFIndex(*(jnp.asarray(a) for a in layout), list_pad)
 
 
 class LiveIndex:
@@ -103,7 +114,7 @@ class LiveIndex:
     """
 
     def __init__(self, index: IVFIndex, *, delta_cap: int = 1024,
-                 align: int = 64, round_total_to: int = 4096, wal=None):
+                 align: int = 128, round_total_to: int = 4096, wal=None):
         validate_alignment(index, blk_l=align)
         self.index = index
         self.align = align
@@ -120,7 +131,7 @@ class LiveIndex:
         self._replaying = False
 
     @classmethod
-    def from_version(cls, ver, *, align: int = 64,
+    def from_version(cls, ver, *, align: int = 128,
                      round_total_to: int = 4096, wal=None) -> "LiveIndex":
         """Rebuild a LiveIndex from a published/restored snapshot
         (``repro.index.registry.IndexVersion``).  The delta buffer and
@@ -249,16 +260,21 @@ class LiveIndex:
             else:
                 fill[cl] += 1
         merged = slots[take]
-        docs_np = np.asarray(self.index.docs)
-        net_vecs = np.concatenate([docs_np[rows], self.delta.vecs[merged]])
+        net_vecs = np.concatenate([np.asarray(self.index.docs)[rows],
+                                   self.delta.vecs[merged]])
         net_ids = np.concatenate(
             [self._doc_ids[rows], self.delta.ids[merged]])
         net_assign = np.concatenate(
             [assign_main, self.delta.assign[merged]])
-        self.index = relayout(net_vecs, net_ids, net_assign,
-                              self._centroids, list_pad=lp,
-                              align=self.align,
-                              round_total_to=self.round_total_to)
+        layout = _layout(net_vecs, net_ids, net_assign, self._centroids,
+                         list_pad=lp, align=self.align,
+                         round_total_to=self.round_total_to)
+        del net_vecs
+        # drop this instance's hold on the old device arrays before the
+        # upload: an index that fills more than half the device can then
+        # be merged once no reader pins the old version
+        self.index = None
+        self.index = _upload(layout, lp)
         self.delta.compact_keep(slots[~take])
         self._refresh_mirrors()
         self.version += 1

@@ -159,7 +159,7 @@ class IndexRegistry:
 
     @staticmethod
     def recover(manager, wal=None, *, step: Optional[int] = None,
-                align: int = 64, round_total_to: int = 4096):
+                align: int = 128, round_total_to: int = 4096):
         """Crash recovery: latest snapshot + WAL replay past it.
 
         Returns ``(registry, live, replay_report)`` where ``live`` is a

@@ -25,6 +25,7 @@ def _kernel(q_ref, v_ref, o_ref):
     v = v_ref[...].astype(jnp.float32)          # (blk_c, d)
     o_ref[...] = jax.lax.dot_general(
         q, v, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)     # (blk_b, blk_c)
 
 

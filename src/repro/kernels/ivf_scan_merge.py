@@ -9,20 +9,18 @@ kernel fuses the paper's whole inner loop — probe -> score -> merge —
 over a *chunk* of probes in a single launch, and (optionally) folds the
 live-mutation delta-buffer scan in as a second stream:
 
-* grid ``(B, chunk)``; for each query ``i`` the kernel walks its
-  ``chunk`` probed clusters one probe per step.
-* cluster tiles live in HBM (``memory_space=ANY``) and stream to VMEM
-  through a double-buffered ``pltpu.emit_pipeline`` whose block index
-  map is the scalar-prefetched ``blk_l``-aligned list offset
-  (``build_index(align=...)`` guarantees alignment): the MXU scores
-  tile ``t`` while the DMA engine copies tile ``t+1``.  On CPU
-  (interpret mode) the same per-tile body runs as an unrolled loop of
-  dynamic-slice reads — ``emit_pipeline`` asserts a real TPU at trace
-  time, so the ``pipelined`` flag is static.
-* raw scores NEVER touch HBM: each ``(blk_l,)`` strip lands in a VMEM
-  scratch accumulator; once a probe's ``list_pad`` strip is complete it
-  is masked by the true list size and merged into the packed running
-  top-k via the shared bitonic network (``kernels/sort.py``): score
+* grid ``(B, chunk, list_pad // blk_l)``: for each query ``i`` the
+  kernel walks its ``chunk`` probed clusters, one ``(blk_l, d)`` tile
+  per step.  The docs / ids BlockSpecs index the tile through the
+  scalar-prefetched ``blk_l``-aligned list offset of slot ``(i, j)``
+  (``build_index(align=...)`` guarantees alignment), so Pallas fetches
+  tile ``t+1`` while the MXU scores tile ``t`` — the same kernel body
+  on the TPU and in interpret mode.
+* raw scores NEVER touch HBM: each ``(1, blk_l)`` row lands in a VMEM
+  strip at lane offset ``t * blk_l`` (a 128-multiple, which Mosaic
+  needs for the dynamic store); once a probe's ``list_pad`` strip is
+  complete it is masked by the true list size and merged into the
+  packed running top-k via the shared bitonic network (``kernels/sort.py``): score
   keys in one int32 word, the doc id in the other, so every
   compare-exchange moves one stacked record instead of three lanes.
 * the per-probe *new-entry count* — and therefore the patience signal
@@ -32,8 +30,8 @@ live-mutation delta-buffer scan in as a second stream:
   Marks are stripped before the snapshot is written.
 * **delta stream** (live mutation, ``repro.index``): the fixed-capacity
   buffer of freshly added vectors is scored ONCE per query (at the
-  chunk's first probe) through a second prefetch pipeline into a VMEM
-  strip, then each entry is merged exactly at the probe slot of its
+  chunk's first step) from HBM through a two-slot DMA buffer into a
+  VMEM strip, then each entry is merged exactly at the probe slot of its
   *assigned* cluster (scalar-prefetched ``gate_cids``; slots past the
   probe budget gate on ``-2`` so they can never match an empty slot's
   ``assign == -1``).  Because the running top-k already carries every
@@ -70,159 +68,136 @@ KEY_NEG = sort.key_of(NEG)
 KEY_VALID = sort.key_of(VALID_MIN)
 
 
-def _score_tiles(docs_ref, ids_ref, bo, sbuf, ibuf, q, *, nblk: int,
-                 blk_l: int, d: int, pipelined: bool) -> None:
-    """Score ``nblk`` (blk_l, d) tiles starting at block row ``bo``.
-
-    ``docs_ref``/``ids_ref`` live in ANY (HBM) space.  Pipelined: a
-    double-buffered ``emit_pipeline`` whose index map adds the
-    prefetched block offset, overlapping each tile's DMA with the
-    previous tile's MXU dot.  Interpret fallback: the same per-tile
-    compute as an unrolled dynamic-slice loop.
-    """
-    def tile_dot(tile, ids):
-        return (jax.lax.dot_general(
-            q, tile.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32), ids)
-
-    if pipelined:
-        def body(doc_t, id_t):
-            t = pl.program_id(0)
-            s, ids = tile_dot(doc_t[...], id_t[...])
-            sbuf[pl.ds(t, 1)] = s
-            ibuf[pl.ds(t, 1)] = ids
-        pltpu.emit_pipeline(
-            body, grid=(nblk,),
-            in_specs=[pl.BlockSpec((blk_l, d), lambda t: (bo + t, 0)),
-                      pl.BlockSpec((1, blk_l), lambda t: (bo + t, 0))],
-            out_specs=(),
-        )(docs_ref, ids_ref)
-    else:
-        for t in range(nblk):
-            tile = docs_ref[pl.ds((bo + t) * blk_l, blk_l), :]
-            ids = ids_ref[pl.ds(bo + t, 1), :]
-            s, ids = tile_dot(tile, ids)
-            sbuf[pl.ds(t, 1)] = s
-            ibuf[pl.ds(t, 1)] = ids
+def _dot(q, tile):
+    """(1, d) x (rows, d)^T -> (1, rows) at full f32 precision."""
+    return jax.lax.dot_general(
+        q, tile.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
-def _score_delta(dvec_ref, dsc, q, *, cap_pad: int, blk_dl: int, d: int,
-                 pipelined: bool) -> None:
-    """Second prefetch stream: score the whole delta buffer into the
-    (1, cap_pad) VMEM strip ``dsc`` (done once per query, at the
-    chunk's first probe slot)."""
-    nblk_d = cap_pad // blk_dl
+def _score_delta(dvec_hbm, dbuf, dsem, dsc, q, *, nblk_d: int,
+                 blk_dl: int) -> None:
+    """Second stream: score the whole delta buffer into the (1, cap_pad)
+    VMEM strip ``dsc`` (once per query, at the chunk's first step).
 
-    def strip_dot(tile):
-        return jax.lax.dot_general(
-            q, tile.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    The buffer stays in HBM; ``(blk_dl, d)`` tiles are copied into a
+    two-slot VMEM buffer so tile ``t + 1`` is in flight while tile ``t``
+    is scored."""
+    def copy(t, slot):
+        return pltpu.make_async_copy(
+            dvec_hbm.at[pl.ds(pl.multiple_of(t * blk_dl, blk_dl), blk_dl)],
+            dbuf.at[slot], dsem.at[slot])
 
-    if pipelined:
-        def body(dv_t):
-            t = pl.program_id(0)
-            dsc[:, pl.ds(t * blk_dl, blk_dl)] = strip_dot(dv_t[...])
-        pltpu.emit_pipeline(
-            body, grid=(nblk_d,),
-            in_specs=[pl.BlockSpec((blk_dl, d), lambda t: (t, 0))],
-            out_specs=(),
-        )(dvec_ref)
-    else:
-        for t in range(nblk_d):
-            tile = dvec_ref[pl.ds(t * blk_dl, blk_dl), :]
-            dsc[:, pl.ds(t * blk_dl, blk_dl)] = strip_dot(tile)
+    copy(0, 0).start()
+
+    def body(t, carry):
+        slot = t % 2
+
+        @pl.when(t + 1 < nblk_d)
+        def _prefetch():
+            copy(t + 1, 1 - slot).start()
+
+        copy(t, slot).wait()
+        lane0 = pl.multiple_of(t * blk_dl, blk_dl)
+        dsc[:, pl.ds(lane0, blk_dl)] = _dot(q, dbuf[slot])
+        return carry
+
+    jax.lax.fori_loop(0, nblk_d, body, 0)
 
 
 def _kernel(*refs, k: int, chunk: int, blk_l: int, nblk: int,
-            list_pad: int, m_pad: int, d: int, pipelined: bool,
-            has_delta: bool, cap_pad: int, blk_dl: int, m2_pad: int):
+            m_pad: int, has_delta: bool, blk_dl: int, nblk_d: int,
+            m2_pad: int):
     if has_delta:
         (boffs_ref, sizes_ref, gates_ref, q_ref, docs_ref, ids_ref,
-         ins_ref, ini_ref, dvec_ref, did_ref, das_ref, outs_ref,
-         outi_ref, cnt_ref, sbuf, ibuf, run_p, dsc) = refs
+         ins_ref, ini_ref, dvec_hbm, did_ref, das_ref, outs_ref,
+         outi_ref, cnt_ref, sbuf, ibuf, run_p, dsc, dbuf, dsem) = refs
     else:
         (boffs_ref, sizes_ref, q_ref, docs_ref, ids_ref, ins_ref,
          ini_ref, outs_ref, outi_ref, cnt_ref, sbuf, ibuf, run_p) = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
+    t = pl.program_id(2)
     q = q_ref[...].astype(jnp.float32)          # (1, d)
 
-    # chunk start: load this query's incoming running top-k into the
+    # a query's first step: load its incoming running top-k into the
     # packed scratch, and score the delta buffer once
-    @pl.when(j == 0)
+    @pl.when((j == 0) & (t == 0))
     def _load_running():
         s0 = jnp.maximum(ins_ref[...], NEG)     # clamp -inf empty slots
         run_p[0:1] = sort.score_to_key(s0)
         run_p[1:2] = ini_ref[...]
         if has_delta:
-            _score_delta(dvec_ref, dsc, q, cap_pad=cap_pad,
-                         blk_dl=blk_dl, d=d, pipelined=pipelined)
+            _score_delta(dvec_hbm, dbuf, dsem, dsc, q, nblk_d=nblk_d,
+                         blk_dl=blk_dl)
 
-    # stream + score this probe's cluster tile (double-buffered on TPU)
-    bo = boffs_ref[i * chunk + j]
-    _score_tiles(docs_ref, ids_ref, bo, sbuf, ibuf, q, nblk=nblk,
-                 blk_l=blk_l, d=d, pipelined=pipelined)
+    # score this step's (blk_l, d) tile of the probed list into the
+    # VMEM strip; the grid pipeline has already fetched the next one
+    lane0 = pl.multiple_of(t * blk_l, blk_l)
+    sbuf[:, pl.ds(lane0, blk_l)] = _dot(q, docs_ref[...])
+    ibuf[:, pl.ds(lane0, blk_l)] = ids_ref[...]
 
-    # merge A: the probe tile, masked by true list size, NEW-marked
-    size = sizes_ref[i * chunk + j]
-    new_s = sbuf[...].reshape(1, list_pad)
-    new_i = ibuf[...].reshape(1, list_pad)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, list_pad), 1)
-    # tombstones: deleted rows keep their vector but their stored id is
-    # burned to -1 (repro.index.live), so masking id < 0 hides both
-    # padding and deleted docs without an extra input stream
-    alive = (lane < size) & (new_i >= 0)
-    new_k = jnp.where(alive, sort.score_to_key(new_s), KEY_NEG)
-    new_iw = jnp.where(alive, new_i | sort.NEW_MARK, -1)
-    res = sort.merge_packed(run_p[...].reshape(1, 2, k), new_k, new_iw,
-                            m_pad, pad_key=KEY_NEG)
-    run_p[...] = res[0, :, :k]
+    @pl.when(t == nblk - 1)
+    def _merge():
+        # merge A: the probe's list, masked by true size, NEW-marked
+        size = sizes_ref[i * chunk + j]
+        new_s = sbuf[...]
+        new_i = ibuf[...]
+        lane = jax.lax.broadcasted_iota(jnp.int32, new_i.shape, 1)
+        # tombstones: deleted rows keep their vector but their stored id
+        # is burned to -1 (repro.index.live), so masking id < 0 hides
+        # both padding and deleted docs without an extra input stream
+        alive = (lane < size) & (new_i >= 0)
+        cand = jnp.concatenate(
+            [jnp.where(alive, sort.score_to_key(new_s), KEY_NEG),
+             jnp.where(alive, new_i | sort.NEW_MARK, -1)], axis=0)
+        run_p[...] = sort.merge_packed(run_p[...], cand, m_pad,
+                                       pad_key=KEY_NEG)[:, :k]
 
-    if has_delta:
-        # merge B: delta entries whose assigned cluster is THIS probe.
-        # Each entry is offered exactly once (its own slot); the running
-        # top-k already holds every earlier merge, so this reproduces
-        # the sequential per-probe reference.
-        gate_cid = gates_ref[i * chunk + j]
-        das = das_ref[...]                       # (1, cap_pad)
-        dio = did_ref[...]                       # (1, cap_pad)
-        gate = (das == gate_cid) & (dio >= 0)
+        if has_delta:
+            # merge B: delta entries whose assigned cluster is THIS
+            # probe.  Each entry is offered exactly once (its own slot);
+            # the running top-k already holds every earlier merge, so
+            # this reproduces the sequential per-probe reference.
+            gate_cid = gates_ref[i * chunk + j]
+            dio = did_ref[...]                   # (1, cap_pad)
+            gate = (das_ref[...] == gate_cid) & (dio >= 0)
 
-        @pl.when(jnp.any(gate))
-        def _merge_delta():
-            dk = jnp.where(gate, sort.score_to_key(dsc[...]), KEY_NEG)
-            diw = jnp.where(gate, dio | sort.NEW_MARK, -1)
-            res2 = sort.merge_packed(run_p[...].reshape(1, 2, k), dk,
-                                     diw, m2_pad, pad_key=KEY_NEG)
-            run_p[...] = res2[0, :, :k]
+            @pl.when(jnp.max(gate.astype(jnp.int32)) > 0)
+            def _merge_delta():
+                dcand = jnp.concatenate(
+                    [jnp.where(gate, sort.score_to_key(dsc[...]), KEY_NEG),
+                     jnp.where(gate, dio | sort.NEW_MARK, -1)], axis=0)
+                run_p[...] = sort.merge_packed(run_p[...], dcand, m2_pad,
+                                               pad_key=KEY_NEG)[:, :k]
 
-    # lanes still NEW-marked survived this probe's merge(s):
-    # phi = 100 * kept / k = 100 * (k - new_entries) / k
-    keys = run_p[0:1, :]
-    idw = run_p[1:2, :]
-    kept = jnp.sum(((keys > KEY_VALID) & ~sort.is_marked(idw))
-                   .astype(jnp.int32))
-    cnt_ref[...] = jnp.full((1, 1), k, jnp.int32) - kept
-    clean = sort.strip_marks(idw)
-    run_p[1:2] = clean
-    outs_ref[...] = sort.key_to_score(keys).reshape(1, 1, k)
-    outi_ref[...] = clean.reshape(1, 1, k)
+        # lanes still NEW-marked survived this probe's merge(s):
+        # phi = 100 * kept / k = 100 * (k - new_entries) / k
+        keys = run_p[0:1, :]
+        idw = run_p[1:2, :]
+        kept = jnp.sum(((keys > KEY_VALID) & ~sort.is_marked(idw))
+                       .astype(jnp.int32), axis=1, keepdims=True)
+        cnt_ref[...] = k - kept
+        clean = sort.strip_marks(idw)
+        run_p[1:2] = clean
+        outs_ref[...] = sort.key_to_score(keys)
+        outi_ref[...] = clean
 
 
 def ivf_scan_merge(queries: jnp.ndarray, docs: jnp.ndarray,
-                   ids2d: jnp.ndarray, block_offsets: jnp.ndarray,
+                   ids3d: jnp.ndarray, block_offsets: jnp.ndarray,
                    sizes: jnp.ndarray, run_scores: jnp.ndarray,
                    run_ids: jnp.ndarray, *, k: int, list_pad: int,
-                   chunk: int, blk_l: int = 64,
+                   chunk: int, blk_l: int = 128,
                    delta_vecs: Optional[jnp.ndarray] = None,
                    delta_ids: Optional[jnp.ndarray] = None,
                    delta_assign: Optional[jnp.ndarray] = None,
                    gate_cids: Optional[jnp.ndarray] = None,
-                   blk_dl: int = 128, pipelined: bool = False,
-                   interpret: bool = False
+                   blk_dl: int = 128, interpret: bool = False
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """queries (B,d); docs (n,d) cluster-major; ids2d (n//blk_l, blk_l)
-    doc ids reshaped row-blocked; block_offsets/sizes (B*chunk,) int32
+    """queries (B,d); docs (n,d) cluster-major; ids3d (n//blk_l, 1,
+    blk_l) doc ids, row-blocked; block_offsets/sizes (B*chunk,) int32
     (offsets in blk_l units); run_scores/run_ids (B,k) incoming top-k.
 
     Optional delta stream: delta_vecs (cap_pad, d) with cap_pad a
@@ -230,11 +205,6 @@ def ivf_scan_merge(queries: jnp.ndarray, docs: jnp.ndarray,
     (id -1 = empty slot, assign -2 on padding), gate_cids (B*chunk,)
     int32 — the probed cluster of each slot, or -2 for slots past the
     probe budget.
-
-    ``pipelined`` (static): double-buffered ``emit_pipeline`` tile
-    streaming; requires a real TPU (the pipeline emitter asserts the
-    target generation at trace time), so interpret mode always runs
-    the unrolled dynamic-slice fallback of the same per-tile body.
 
     Returns per-probe snapshots (B, chunk, k) scores (NEG sentinel for
     empty slots) / ids, and (B, chunk) int32 new-entry counts.
@@ -247,63 +217,77 @@ def ivf_scan_merge(queries: jnp.ndarray, docs: jnp.ndarray,
     if has_delta:
         cap_pad = delta_vecs.shape[0]
         assert cap_pad % blk_dl == 0, "delta cap must be blk_dl-padded"
+        nblk_d = cap_pad // blk_dl
         m2_pad = 1 << int(np.ceil(np.log2(k + cap_pad)))
     else:
-        cap_pad, m2_pad = 0, 0
-    npf = 3 if has_delta else 2      # trailing scalar-prefetch ref args
+        cap_pad, nblk_d, m2_pad = 0, 0, 0
 
-    def at_query(i, j, *_):
-        return (i, 0)
+    # Every per-query array carries a unit sublane dim behind a
+    # squeezed leading one: a (1, w) block over (B, w) would break the
+    # TPU's (8, 128) tiling rule, a (None, 1, w) block over (B, 1, w)
+    # does not.
+    def at_query(i, j, t, *_):
+        return (i, 0, 0)
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    def tile_of(i, j, t, boffs, *_):
+        return (boffs[i * chunk + j] + t, 0)
+
+    def ids_of(i, j, t, boffs, *_):
+        return (boffs[i * chunk + j] + t, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, d), at_query),          # queries
-        any_spec,                                # docs (HBM, pipelined)
-        any_spec,                                # ids2d
-        pl.BlockSpec((1, k), at_query),          # run_scores
-        pl.BlockSpec((1, k), at_query),          # run_ids
+        pl.BlockSpec((None, 1, d), at_query),          # queries
+        pl.BlockSpec((blk_l, d), tile_of),             # docs
+        pl.BlockSpec((None, 1, blk_l), ids_of),        # ids
+        pl.BlockSpec((None, 1, k), at_query),          # run_scores
+        pl.BlockSpec((None, 1, k), at_query),          # run_ids
     ]
-    inputs = [queries, docs, ids2d, run_scores, run_ids]
+    inputs = [queries[:, None, :], docs, ids3d, run_scores[:, None, :],
+              run_ids[:, None, :]]
+    scratch = [
+        pltpu.VMEM((1, list_pad), jnp.float32),   # probe score strip
+        pltpu.VMEM((1, list_pad), jnp.int32),     # probe id strip
+        pltpu.VMEM((2, k), jnp.int32),            # packed running top-k
+    ]
     if has_delta:
+        whole = lambda *_: (0, 0)
         in_specs += [
-            any_spec,                            # delta vecs (HBM)
-            pl.BlockSpec((1, cap_pad), lambda *_: (0, 0)),
-            pl.BlockSpec((1, cap_pad), lambda *_: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),  # vecs
+            pl.BlockSpec((1, cap_pad), whole),
+            pl.BlockSpec((1, cap_pad), whole),
         ]
         inputs += [delta_vecs, delta_ids.reshape(1, cap_pad),
                    delta_assign.reshape(1, cap_pad)]
-    scratch = [
-        pltpu.VMEM((nblk, blk_l), jnp.float32),  # probe score strip
-        pltpu.VMEM((nblk, blk_l), jnp.int32),    # probe id strip
-        pltpu.VMEM((2, k), jnp.int32),           # packed running top-k
-    ]
-    if has_delta:
-        scratch.append(pltpu.VMEM((1, cap_pad), jnp.float32))
+        scratch += [
+            pltpu.VMEM((1, cap_pad), jnp.float32),     # delta scores
+            pltpu.VMEM((2, blk_dl, d), delta_vecs.dtype),  # tile slots
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+
+    def per_slot(i, j, t, *_):
+        return (i, j, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=npf,
-        grid=(b, chunk),
+        num_scalar_prefetch=3 if has_delta else 2,
+        grid=(b, chunk, nblk),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda i, j, *_: (i, j, 0)),
-            pl.BlockSpec((1, 1, k), lambda i, j, *_: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, *_: (i, j)),
-        ],
+        out_specs=[pl.BlockSpec((None, None, 1, k), per_slot),
+                   pl.BlockSpec((None, None, 1, k), per_slot),
+                   pl.BlockSpec((None, None, 1, 1), per_slot)],
         scratch_shapes=scratch,
     )
     kern = functools.partial(
-        _kernel, k=k, chunk=chunk, blk_l=blk_l, nblk=nblk,
-        list_pad=list_pad, m_pad=m_pad, d=d, pipelined=pipelined,
-        has_delta=has_delta, cap_pad=cap_pad, blk_dl=blk_dl,
-        m2_pad=m2_pad)
+        _kernel, k=k, chunk=chunk, blk_l=blk_l, nblk=nblk, m_pad=m_pad,
+        has_delta=has_delta, blk_dl=blk_dl, nblk_d=nblk_d, m2_pad=m2_pad)
     prefetch = [block_offsets.astype(jnp.int32), sizes.astype(jnp.int32)]
     if has_delta:
         prefetch.append(gate_cids.astype(jnp.int32))
-    return pl.pallas_call(
+    out_s, out_i, cnt = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, chunk, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, chunk, k), jnp.int32),
-                   jax.ShapeDtypeStruct((b, chunk), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((b, chunk, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((b, chunk, 1, k), jnp.int32),
+                   jax.ShapeDtypeStruct((b, chunk, 1, 1), jnp.int32)],
         interpret=interpret,
     )(*prefetch, *inputs)
+    return out_s[:, :, 0], out_i[:, :, 0], cnt[:, :, 0, 0]

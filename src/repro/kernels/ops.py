@@ -35,7 +35,7 @@ def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
 
 @functools.partial(jax.jit, static_argnames=("list_pad", "blk_l"))
 def ivf_scan(queries, docs, offsets, sizes, *, list_pad: int,
-             blk_l: int = 64):
+             blk_l: int = 128):
     """Fused cluster-tile scoring; -inf outside each true list size."""
     raw = _scan.ivf_scan(queries, docs, offsets, list_pad=list_pad,
                          blk_l=blk_l, interpret=_interpret())
@@ -49,7 +49,7 @@ def ivf_scan(queries, docs, offsets, sizes, *, list_pad: int,
 def ivf_scan_merge(queries, docs, doc_ids, offsets, sizes, run_scores,
                    run_ids, delta_vecs=None, delta_ids=None,
                    delta_assign=None, gate_cids=None, *, k: int,
-                   list_pad: int, chunk: int, blk_l: int = 64,
+                   list_pad: int, chunk: int, blk_l: int = 128,
                    blk_dl: int = 128
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused multi-probe scan -> running top-k merge (one dispatch per
@@ -72,8 +72,8 @@ def ivf_scan_merge(queries, docs, doc_ids, offsets, sizes, run_scores,
     """
     n = doc_ids.shape[0]
     tail = (-n) % blk_l
-    ids2d = jnp.pad(doc_ids, (0, tail),
-                    constant_values=-1).reshape(-1, blk_l)
+    ids3d = jnp.pad(doc_ids, (0, tail),
+                    constant_values=-1).reshape(-1, 1, blk_l)
     kw = {}
     if delta_vecs is not None:
         cap = delta_vecs.shape[0]
@@ -87,11 +87,10 @@ def ivf_scan_merge(queries, docs, doc_ids, offsets, sizes, run_scores,
                                  constant_values=-2),
             gate_cids=gate_cids.reshape(-1), blk_dl=blk_dl)
     out_s, out_i, cnt = _sm.ivf_scan_merge(
-        queries, docs, ids2d,
+        queries, docs, ids3d,
         (offsets // blk_l).reshape(-1), sizes.reshape(-1),
         run_scores, run_ids, k=k, list_pad=list_pad, chunk=chunk,
-        blk_l=blk_l, pipelined=not _interpret(),
-        interpret=_interpret(), **kw)
+        blk_l=blk_l, interpret=_interpret(), **kw)
     # sentinel -> -inf so empty slots match the XLA merge convention
     out_s = jnp.where(out_s > _sm.VALID_MIN, out_s, -jnp.inf)
     return out_s, out_i, cnt

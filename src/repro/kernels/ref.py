@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         *, causal: bool = True) -> jnp.ndarray:
@@ -31,7 +33,7 @@ def ivf_scan_ref(queries: jnp.ndarray, docs: jnp.ndarray,
     tiles = jax.vmap(lambda o: jax.lax.dynamic_slice_in_dim(
         docs, o, list_pad, 0))(offsets)
     sc = jnp.einsum("bld,bd->bl", tiles.astype(jnp.float32),
-                    queries.astype(jnp.float32))
+                    queries.astype(jnp.float32), precision=_HIGHEST)
     mask = jnp.arange(list_pad)[None] < sizes[:, None]
     return jnp.where(mask, sc, -jnp.inf)
 
@@ -69,7 +71,7 @@ def ivf_scan_merge_ref(queries: jnp.ndarray, docs: jnp.ndarray,
         tids = jax.vmap(lambda o: jax.lax.dynamic_slice_in_dim(
             doc_ids, o, list_pad, 0))(offsets[:, t])
         sc = jnp.einsum("bld,bd->bl", tiles.astype(jnp.float32),
-                        queries.astype(jnp.float32))
+                        queries.astype(jnp.float32), precision=_HIGHEST)
         mask = jnp.arange(list_pad)[None] < sizes[:, t][:, None]
         tids = jnp.where(mask, tids, -1)
         # id < 0 == padding or tombstoned row: never a candidate
@@ -87,7 +89,8 @@ def ivf_scan_merge_ref(queries: jnp.ndarray, docs: jnp.ndarray,
 
 def delta_scan_ref(queries: jnp.ndarray, vecs: jnp.ndarray) -> jnp.ndarray:
     """queries (B,d) x delta vecs (cap,d) -> (B,cap) raw f32 scores."""
-    return queries.astype(jnp.float32) @ vecs.astype(jnp.float32).T
+    return jnp.matmul(queries.astype(jnp.float32),
+                      vecs.astype(jnp.float32).T, precision=_HIGHEST)
 
 
 def embedding_bag_ref(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:

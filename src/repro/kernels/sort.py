@@ -10,13 +10,13 @@ denormals and ±inf.  NaNs map above +inf; callers that may see NaN
 clamp it first (``topk_merge`` maps every non-finite score to the
 ``-1e30`` sentinel).
 
-The sort then runs on a single stacked ``(R, 2, M)`` int32 array —
-key word and id word — instead of separate f32 score / i32 id / i32
-tag lanes: each compare-exchange pass costs ONE partner shuffle and
-ONE select of the stacked array (plus one lexicographic compare),
-where the tagged three-lane network paid three of each.  That halves
-shuffle traffic and register pressure in every merge step of the
-fused kernel.
+The sort then runs on a single stacked ``(..., 2, M)`` int32 array —
+key word in sublane 0, id word in sublane 1 — instead of separate f32
+score / i32 id / i32 tag lanes: each compare-exchange pass moves the
+whole record with two lane rotations and one select (plus one
+lexicographic compare), where the tagged three-lane network paid three
+shuffles and three selects.  On a TPU the two words share one vreg per
+128 lanes, so a pass costs what a one-word network would.
 
 Ties: descending lexicographic on (key, id-word), so equal scores are
 broken by the *higher* id word deterministically.  The per-probe
@@ -37,6 +37,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 _SIGN_FLIP = 0x7FFFFFFF          # flips magnitude bits of negatives
 NEW_MARK = 1 << 30               # id-word bit: entered on this probe
@@ -76,61 +77,72 @@ def is_marked(idw: jnp.ndarray) -> jnp.ndarray:
 
 
 def pack(keys: jnp.ndarray, idw: jnp.ndarray) -> jnp.ndarray:
-    """Stack (R, M) key / id-word lanes into the (R, 2, M) sort form."""
-    return jnp.stack([keys, idw], axis=1)
+    """Stack (..., M) key / id-word lanes into the (..., 2, M) sort form."""
+    return jnp.stack([keys, idw], axis=-2)
 
 
+@jax.jit
 def bitonic_desc_packed(x: jnp.ndarray) -> jnp.ndarray:
-    """Sort a packed (R, 2, M) array descending by (key, id word).
+    """Sort a packed (..., 2, M) array descending by (key, id word).
 
-    M must be a power of two.  The lane ^ jj partner permutation of
-    each compare-exchange pass is a reshape + reverse on a length-2
-    axis (flip one address bit), which lowers to cheap lane shuffles
-    and — unlike gather formulations — keeps compile time flat in the
-    network depth.  Both words ride the same ``take_p`` mask: one
-    shuffle + one select per pass for the whole record.
+    Jitted because ``pltpu.roll`` has no eager rule; inside a kernel
+    or another jit the call is inlined.
+
+    M must be a power of two.  The lane ^ jj partner of each
+    compare-exchange pass comes from two lane rotations by ``jj``
+    (``pltpu.roll``: an XLU rotate under Mosaic, ``jnp.roll`` under
+    XLA and in interpret mode), selected by bit ``jj`` of the lane
+    index.  Both words ride the same rotation and the same ``take_p``
+    mask: two rotates + one select per pass for the whole record.
     """
-    r, two, m = x.shape
-    assert two == 2 and m & (m - 1) == 0, (r, two, m)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, m), 2)
+    *lead, two, m = x.shape
+    assert two == 2 and m & (m - 1) == 0, x.shape
+    axis = x.ndim - 1
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1,) * len(lead) + (1, m),
+                                   axis)
     stages = int(np.log2(m))
 
     def partner(v, jj):
-        v5 = v.reshape(r, 2, m // (2 * jj), 2, jj)
-        return jnp.flip(v5, axis=3).reshape(r, 2, m)
+        # lane i reads lane i + jj when bit jj of i is clear, else i - jj
+        return jnp.where((idx & jj) == 0, pltpu.roll(v, m - jj, axis),
+                         pltpu.roll(v, jj, axis))
 
     for stage in range(1, stages + 1):
         kk = 1 << stage
         for jj in (1 << p for p in range(stage - 1, -1, -1)):
             # keep the max in descending blocks' low lanes and
             # ascending blocks' high lanes
-            keep_max = jnp.where((idx & kk) == 0,
-                                 (idx & jj) == 0,
-                                 (idx & jj) != 0)
+            keep_max = ((idx & kk) == 0) ^ ((idx & jj) != 0)
             p = partner(x, jj)
-            pk, pi = p[:, 0:1], p[:, 1:2]
-            xk, xi = x[:, 0:1], x[:, 1:2]
+            pk, pi = p[..., 0:1, :], p[..., 1:2, :]
+            xk, xi = x[..., 0:1, :], x[..., 1:2, :]
             k_eq = pk == xk
             p_gt = (pk > xk) | (k_eq & (pi > xi))
             p_lt = (pk < xk) | (k_eq & (pi < xi))
-            take_p = jnp.where(keep_max, p_gt, p_lt)
+            take_p = (keep_max & p_gt) | (~keep_max & p_lt)
             x = jnp.where(take_p, p, x)
     return x
 
 
-def merge_packed(run: jnp.ndarray, new_keys: jnp.ndarray,
-                 new_idw: jnp.ndarray, m_pad: int,
-                 *, pad_key: int) -> jnp.ndarray:
-    """Merge a packed running (R, 2, K) state with (R, M) candidates.
+def pad_lanes(x: jnp.ndarray, m_pad: int, *, pad_key: int) -> jnp.ndarray:
+    """Pad a packed (..., 2, M) array to ``m_pad`` lanes with
+    (pad_key, -1) records, which sink below every real candidate."""
+    pad = m_pad - x.shape[-1]
+    if not pad:
+        return x
+    shape = x.shape[:-1] + (pad,)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, x.ndim - 2)
+    fill = jnp.where(row == 0, jnp.int32(pad_key), jnp.int32(-1))
+    return jnp.concatenate([x, fill], axis=-1)
 
-    Pads the concatenation to ``m_pad`` lanes with (pad_key, -1) and
-    returns the full sorted (R, 2, m_pad) network output; callers slice
-    the leading K lanes back into their running state.
+
+def merge_packed(run: jnp.ndarray, cand: jnp.ndarray, m_pad: int,
+                 *, pad_key: int) -> jnp.ndarray:
+    """Merge a packed running (..., 2, K) state with packed (..., 2, M)
+    candidates.
+
+    Returns the full sorted (..., 2, m_pad) network output; callers
+    slice the leading K lanes back into their running state.
     """
-    ck = jnp.concatenate([run[:, 0], new_keys], axis=1)
-    ci = jnp.concatenate([run[:, 1], new_idw], axis=1)
-    pad = m_pad - ck.shape[1]
-    if pad:
-        ck = jnp.pad(ck, ((0, 0), (0, pad)), constant_values=pad_key)
-        ci = jnp.pad(ci, ((0, 0), (0, pad)), constant_values=-1)
-    return bitonic_desc_packed(pack(ck, ci))
+    return bitonic_desc_packed(pad_lanes(
+        jnp.concatenate([run, cand], axis=-1), m_pad, pad_key=pad_key))

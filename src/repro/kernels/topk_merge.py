@@ -25,18 +25,18 @@ _KEY_NEG = sort.key_of(-1e30)
 
 def _kernel(s_ref, i_ref, ns_ref, ni_ref, os_ref, oi_ref, *, k: int,
             m_pad: int):
-    s = jnp.concatenate([s_ref[...], ns_ref[...]], axis=1)
-    i = jnp.concatenate([i_ref[...], ni_ref[...]], axis=1)
-    pad = m_pad - s.shape[1]
-    if pad:
-        s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-1e30)
-        i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
+    # every ref is (blk_b, 1, width): the unit sublane row keeps the
+    # (key, id) pack a sublane concat, with no in-kernel shape cast
+    s = jnp.concatenate([s_ref[...], ns_ref[...]], axis=-1)
+    i = jnp.concatenate([i_ref[...], ni_ref[...]], axis=-1)
     # NaN/±inf clamp BEFORE the key map: every non-finite score becomes
     # the -1e30 sentinel, so NaNs cannot leak above +inf in key space
     s = jnp.where(jnp.isfinite(s), s, -1e30)
-    out = sort.bitonic_desc_packed(sort.pack(sort.score_to_key(s), i))
-    os_ref[...] = sort.key_to_score(out[:, 0, :k])
-    oi_ref[...] = out[:, 1, :k]
+    cand = jnp.concatenate([sort.score_to_key(s), i], axis=-2)
+    out = sort.bitonic_desc_packed(
+        sort.pad_lanes(cand, m_pad, pad_key=_KEY_NEG))
+    os_ref[...] = sort.key_to_score(out[:, 0:1, :k])
+    oi_ref[...] = out[:, 1:2, :k]
 
 
 def topk_merge(scores: jnp.ndarray, ids: jnp.ndarray,
@@ -47,20 +47,25 @@ def topk_merge(scores: jnp.ndarray, ids: jnp.ndarray,
     total = scores.shape[1] + new_scores.shape[1]
     m_pad = 1 << int(np.ceil(np.log2(total)))
     blk_b = min(blk_b, b)
-    if b % blk_b:
-        blk_b = 1
+    bp = -(-b // blk_b) * blk_b
     kern = functools.partial(_kernel, k=k, m_pad=m_pad)
-    grid = (b // blk_b,)
-    specs = lambda w: pl.BlockSpec((blk_b, w), lambda bi: (bi, 0))
+
+    def rows(x, fill):
+        x = jnp.pad(x, ((0, bp - b), (0, 0)), constant_values=fill)
+        return x[:, None, :]
+
+    spec = lambda w: pl.BlockSpec((blk_b, 1, w), lambda bi: (bi, 0, 0))
     out_s, out_i = pl.pallas_call(
-        kern, grid=grid,
-        in_specs=[specs(scores.shape[1]), specs(ids.shape[1]),
-                  specs(new_scores.shape[1]), specs(new_ids.shape[1])],
-        out_specs=[specs(k), specs(k)],
-        out_shape=[jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, k), ids.dtype)],
+        kern, grid=(bp // blk_b,),
+        in_specs=[spec(scores.shape[1]), spec(ids.shape[1]),
+                  spec(new_scores.shape[1]), spec(new_ids.shape[1])],
+        out_specs=[spec(k), spec(k)],
+        out_shape=[jax.ShapeDtypeStruct((bp, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((bp, 1, k), ids.dtype)],
         interpret=interpret,
-    )(scores, ids, new_scores, new_ids)
+    )(rows(scores, -jnp.inf), rows(ids, -1), rows(new_scores, -jnp.inf),
+      rows(new_ids, -1))
+    out_s, out_i = out_s[:b, 0], out_i[:b, 0]
     # the kernel clamps -inf to -1e30 for the sort network; map the
     # sentinel back so empty slots match the XLA merge (-inf) exactly
     out_s = jnp.where(out_s > -1e29, out_s, NEG_INF)

@@ -483,7 +483,7 @@ def run_drift_drill(cfg: ChaosConfig, *, k: int = 10, dim: int = 32,
     base = np.zeros((2048, dim), np.float32)
     base[:, :half] = rng.normal(size=(2048, half))
     index = build_index(base, n_clusters=n_clusters, list_pad=256,
-                        seed=cfg.seed, align=64)
+                        seed=cfg.seed)
     centers = rng.normal(scale=4.0, size=(8, half)).astype(np.float32)
     doomed = rng.permutation(2048)
 

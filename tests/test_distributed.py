@@ -18,6 +18,7 @@ def _run(code: str) -> dict:
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         "--xla_disable_hlo_passes=all-reduce-promotion")
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"     # a child never contends for a chip
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
