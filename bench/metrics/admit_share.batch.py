@@ -1,0 +1,2 @@
+"""``admit_share`` under the batch traffic (see ``bench/readers.py``)."""
+from bench.readers import admit_share as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""``idle_share`` under the steady traffic (see ``bench/readers.py``)."""
+from bench.readers import idle_share as read  # noqa: F401
