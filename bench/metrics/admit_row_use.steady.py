@@ -1,0 +1,2 @@
+"""``admit_row_use`` under the steady traffic (see ``bench/stages.py``)."""
+from bench.stages import admit_row_use as read  # noqa: F401

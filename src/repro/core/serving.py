@@ -12,6 +12,7 @@ gather/scatters on device; the host loop only moves query ids.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
@@ -203,6 +204,51 @@ def _advance(index: IVFIndex, state: LaneState,
 _REASON_RANK = {"tightened_patience": 1, "capped_probes": 2,
                 "forced_exit": 3, "shed": 4}
 
+#: The serve loop's stages in loop order: the columns of
+#: ``ServeReport.stage_ms`` and, prefixed ``serve.``, the names of their
+#: profiler spans.  The ``WAIT_STAGES`` block on the device (a pull of
+#: ``state.active`` after ``_advance`` / ``_admit``); the rest is host
+#: work.
+STAGES = ("wait_advance", "harvest", "pin", "ladder", "admit",
+          "wait_admit", "advance", "rebuild")
+WAIT_STAGES = ("wait_advance", "wait_admit")
+
+
+class _StageClock:
+    """Per-wave host time of each stage in :data:`STAGES`.
+
+    ``with stage("admit"):`` opens a profiler span ``serve.admit``, on
+    the host plane of the trace and the device planes' clock (so a
+    device idle gap inside it is put down to that stage), and adds the
+    block's ``time.perf_counter`` ms to the current wave's row.  It is
+    the host's real clock, not the scheduler's injectable ``clock``,
+    which is the traffic's.  With the profiler off a stage costs a few
+    microseconds: one inactive ``TraceMe`` and two clock reads."""
+
+    def __init__(self):
+        self.rows: List[List[float]] = []
+        self.row = [0.0] * len(STAGES)
+
+    @contextlib.contextmanager
+    def __call__(self, stage: str):
+        with jax.profiler.TraceAnnotation("serve." + stage):
+            t = time.perf_counter()
+            yield
+            self.row[STAGES.index(stage)] += 1e3 * (time.perf_counter() - t)
+
+    def end_wave(self) -> None:
+        self.rows.append(self.row)
+        self.row = [0.0] * len(STAGES)
+
+    def ms(self) -> np.ndarray:
+        """(waves, len(STAGES)) ms; the loop's last pass, which
+        dispatches nothing, adds into the last wave's row."""
+        out = np.zeros((len(self.rows), len(STAGES)))
+        if self.rows:
+            out[:] = self.rows
+            out[-1] += self.row
+        return out
+
 
 @dataclasses.dataclass
 class ServeReport:
@@ -221,6 +267,12 @@ class ServeReport:
     drain_waves: int = 0        # waves spent draining before a swap
     rebuild_ticks: int = 0      # rebuild stages run between waves
     rebuild_throttled: int = 0  # ticks skipped under deadline pressure
+    # -- the loop's own stages and admission counters --
+    stage_ms: np.ndarray = dataclasses.field(       # (waves, len(STAGES))
+        default_factory=lambda: np.zeros((0, len(STAGES))))
+    empty_waves: int = 0        # waves dispatched with no active lane
+    admit_calls: int = 0        # _admit dispatches
+    admitted: int = 0           # queries placed in a lane
 
     @property
     def degraded_fraction(self) -> float:
@@ -363,20 +415,25 @@ class WaveScheduler:
         wave_cost = 0.0                              # EMA of wave ms
         epoch_swaps = drain_waves = 0
         rebuild_ticks = rebuild_throttled = 0
+        empty_waves = admit_calls = admitted = 0
+        stage = _StageClock()
         self._pinned = None if self.registry is None \
             else self.registry.current()
         while True:
-            active = np.asarray(state.active)
-            qids = np.asarray(state.qid)
-            now = self._now()
-            # harvest exits: lanes that flipped active->inactive
-            for lane in np.nonzero(prev_active & ~active)[0]:
-                qid = int(np.asarray(prev_state.qid)[lane])
-                results[qid] = np.asarray(state.topk_ids)[lane]
-                probes[qid] = int(np.asarray(state.h)[lane])
-                latency[qid] = now - lane_admit[lane]
+            with stage("wait_advance"):
+                active = np.asarray(state.active)
+            with stage("harvest"):
+                qids = np.asarray(state.qid)
+                now = self._now()
+                # harvest exits: lanes that flipped active->inactive
+                for lane in np.nonzero(prev_active & ~active)[0]:
+                    qid = int(np.asarray(prev_state.qid)[lane])
+                    results[qid] = np.asarray(state.topk_ids)[lane]
+                    probes[qid] = int(np.asarray(state.h)[lane])
+                    latency[qid] = now - lane_admit[lane]
             # -- epoch-fenced version adoption ------------------------------
-            draining, swapped = self._refresh_pin(bool(active.any()))
+            with stage("pin"):
+                draining, swapped = self._refresh_pin(bool(active.any()))
             if swapped:
                 epoch_swaps += 1
             if draining:
@@ -384,40 +441,43 @@ class WaveScheduler:
             # -- degradation ladder (deadline-budgeted serving) -------------
             lane_delta, lane_cap = full_delta, full_cap
             if self.deadline_ms is not None:
-                remaining = self.deadline_ms - (now - lane_admit)
-                rungs = self.ladder.rungs(remaining, max(wave_cost, 1e-9))
-                rungs = np.where(active, rungs, RUNG_NONE)
-                force = active & (rungs == RUNG_FORCE)
-                if force.any():
-                    h_np = np.asarray(state.h)
-                    tid = np.asarray(state.topk_ids)
-                    for lane in np.nonzero(force)[0]:
-                        qid = int(qids[lane])
-                        results[qid] = tid[lane]
-                        probes[qid] = int(h_np[lane])
-                        latency[qid] = now - lane_admit[lane]
-                        self._flag(degraded, qid, "forced_exit")
-                    active = active & ~force
-                    state = state._replace(active=jnp.asarray(active))
-                for lane in np.nonzero(active
-                                       & (rungs >= RUNG_TIGHTEN))[0]:
-                    self._flag(degraded, int(qids[lane]),
-                               "capped_probes" if rungs[lane] >= RUNG_CAP
-                               else "tightened_patience")
-                if (rungs > RUNG_NONE).any():
-                    h_np = np.asarray(state.h)
-                    afford = np.floor(
-                        np.maximum(remaining, 0.0)
-                        / max(wave_cost, 1e-9)).astype(np.int64) \
-                        * self.chunk
-                    cap_np = np.where(rungs >= RUNG_CAP, h_np + afford,
-                                      self.n)
-                    cap_np = np.minimum(cap_np, self.n).astype(np.int32)
-                    tight = min(self.ladder.tight_delta, self.delta)
-                    delta_np = np.where(rungs >= RUNG_TIGHTEN, tight,
-                                        self.delta).astype(np.int32)
-                    lane_delta = jnp.asarray(delta_np)
-                    lane_cap = jnp.asarray(cap_np)
+                with stage("ladder"):
+                    remaining = self.deadline_ms - (now - lane_admit)
+                    rungs = self.ladder.rungs(remaining,
+                                              max(wave_cost, 1e-9))
+                    rungs = np.where(active, rungs, RUNG_NONE)
+                    force = active & (rungs == RUNG_FORCE)
+                    if force.any():
+                        h_np = np.asarray(state.h)
+                        tid = np.asarray(state.topk_ids)
+                        for lane in np.nonzero(force)[0]:
+                            qid = int(qids[lane])
+                            results[qid] = tid[lane]
+                            probes[qid] = int(h_np[lane])
+                            latency[qid] = now - lane_admit[lane]
+                            self._flag(degraded, qid, "forced_exit")
+                        active = active & ~force
+                        state = state._replace(active=jnp.asarray(active))
+                    for lane in np.nonzero(active
+                                           & (rungs >= RUNG_TIGHTEN))[0]:
+                        self._flag(degraded, int(qids[lane]),
+                                   "capped_probes"
+                                   if rungs[lane] >= RUNG_CAP
+                                   else "tightened_patience")
+                    if (rungs > RUNG_NONE).any():
+                        h_np = np.asarray(state.h)
+                        afford = np.floor(
+                            np.maximum(remaining, 0.0)
+                            / max(wave_cost, 1e-9)).astype(np.int64) \
+                            * self.chunk
+                        cap_np = np.where(rungs >= RUNG_CAP, h_np + afford,
+                                          self.n)
+                        cap_np = np.minimum(cap_np, self.n).astype(np.int32)
+                        tight = min(self.ladder.tight_delta, self.delta)
+                        delta_np = np.where(rungs >= RUNG_TIGHTEN, tight,
+                                            self.delta).astype(np.int32)
+                        lane_delta = jnp.asarray(delta_np)
+                        lane_cap = jnp.asarray(cap_np)
             # -- admission (with overload shedding) -------------------------
             if (compact or not active.any()) and not draining:
                 if next_q < nq and (~active).any():
@@ -426,39 +486,48 @@ class WaveScheduler:
                             and wave_cost > self.deadline_ms:
                         # even a fresh query cannot meet the deadline:
                         # shed instead of admitting to certain death
-                        for qid in range(next_q,
-                                         min(nq, next_q + room)):
-                            results[qid] = np.full(self.k, -1, np.int32)
-                            probes[qid] = 0
-                            latency[qid] = 0.0
-                            self._flag(degraded, qid, "shed")
-                        next_q = min(nq, next_q + room)
+                        with stage("admit"):
+                            for qid in range(next_q,
+                                             min(nq, next_q + room)):
+                                results[qid] = np.full(self.k, -1,
+                                                       np.int32)
+                                probes[qid] = 0
+                                latency[qid] = 0.0
+                                self._flag(degraded, qid, "shed")
+                            next_q = min(nq, next_q + room)
                     else:
-                        batch = queries[next_q: next_q + room]
-                        m = batch.shape[0]
-                        qpad = np.zeros((self.w, d), np.float32)
-                        qpad[:m] = batch
-                        ids = np.full(self.w, -1, np.int32)
-                        ids[:m] = np.arange(next_q, next_q + m)
-                        before = active
-                        state = _admit(state, self._centroids(),
-                                       jnp.asarray(qpad),
-                                       jnp.asarray(ids), self.n)
+                        with stage("admit"):
+                            batch = queries[next_q: next_q + room]
+                            m = batch.shape[0]
+                            qpad = np.zeros((self.w, d), np.float32)
+                            qpad[:m] = batch
+                            ids = np.full(self.w, -1, np.int32)
+                            ids[:m] = np.arange(next_q, next_q + m)
+                            before = active
+                            state = _admit(state, self._centroids(),
+                                           jnp.asarray(qpad),
+                                           jnp.asarray(ids), self.n)
+                        admit_calls += 1
                         next_q += m
-                        newly = np.asarray(state.active) & ~before
+                        with stage("wait_admit"):
+                            newly = np.asarray(state.active) & ~before
                         lane_admit[newly] = now
+                        admitted += int(newly.sum())
             active = np.asarray(state.active)
             if not active.any() and next_q >= nq:
                 break
+            empty_waves += int(not active.any())
             occ.append(active.mean())
             lane_steps += self.w * self.chunk
             prev_active = active
             prev_state = state
-            index, dview, dead = self._version()
-            state = _advance(index, state, dview, dead,
-                             lane_delta=lane_delta, lane_cap=lane_cap,
-                             chunk=self.chunk, k=self.k, n_probe=self.n,
-                             phi=self.phi, use_fused=self.use_fused)
+            with stage("advance"):
+                index, dview, dead = self._version()
+                state = _advance(index, state, dview, dead,
+                                 lane_delta=lane_delta, lane_cap=lane_cap,
+                                 chunk=self.chunk, k=self.k,
+                                 n_probe=self.n, phi=self.phi,
+                                 use_fused=self.use_fused)
             waves += 1
             if on_wave is not None:
                 on_wave(waves)
@@ -469,18 +538,20 @@ class WaveScheduler:
             # after the wave-cost sample so the stall never inflates
             # the EMA the ladder budgets against
             if self.rebuilder is not None and self.rebuilder.active:
-                throttle = False
-                if self.deadline_ms is not None:
-                    act_now = np.asarray(state.active)
-                    rem = (self.deadline_ms
-                           - (self._now() - lane_admit))[act_now]
-                    throttle = self.ladder.throttle_rebuild(
-                        rem, max(wave_cost, 1e-9))
-                if throttle:
-                    rebuild_throttled += 1
-                else:
-                    self.rebuilder.tick()
-                    rebuild_ticks += 1
+                with stage("rebuild"):
+                    throttle = False
+                    if self.deadline_ms is not None:
+                        act_now = np.asarray(state.active)
+                        rem = (self.deadline_ms
+                               - (self._now() - lane_admit))[act_now]
+                        throttle = self.ladder.throttle_rebuild(
+                            rem, max(wave_cost, 1e-9))
+                    if throttle:
+                        rebuild_throttled += 1
+                    else:
+                        self.rebuilder.tick()
+                        rebuild_ticks += 1
+            stage.end_wave()
         return ServeReport(results, probes, waves,
                            float(np.mean(occ)) if occ else 0.0,
                            lane_steps, degraded=degraded,
@@ -490,4 +561,6 @@ class WaveScheduler:
                            epoch_swaps=epoch_swaps,
                            drain_waves=drain_waves,
                            rebuild_ticks=rebuild_ticks,
-                           rebuild_throttled=rebuild_throttled)
+                           rebuild_throttled=rebuild_throttled,
+                           stage_ms=stage.ms(), empty_waves=empty_waves,
+                           admit_calls=admit_calls, admitted=admitted)

@@ -289,5 +289,6 @@ def ivf_scan_merge(queries: jnp.ndarray, docs: jnp.ndarray,
                    jax.ShapeDtypeStruct((b, chunk, 1, k), jnp.int32),
                    jax.ShapeDtypeStruct((b, chunk, 1, 1), jnp.int32)],
         interpret=interpret,
+        name="ivf_scan_merge",      # the op name a device trace shows
     )(*prefetch, *inputs)
     return out_s[:, :, 0], out_i[:, :, 0], cnt[:, :, 0, 0]

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import build_index, exact_topk, metrics, policies, search
-from repro.core.serving import WaveScheduler
+from repro.core.serving import STAGES, WaveScheduler
 from repro.data.synthetic import clustered_corpus
 from repro.index import DeltaFull, IndexRegistry, LiveIndex, version_of
 from repro.launch import compile_cache
@@ -164,6 +164,12 @@ def main() -> None:
     summ = metrics.summarize(ids, probes, exact, c.relevant, wall)
     summ["occupancy"] = round(rep.occupancy, 3)
     summ["waves"] = rep.waves
+    summ["empty_waves"] = rep.empty_waves
+    summ["admit_calls"] = rep.admit_calls
+    if rep.waves:       # per stage: median and max ms over waves
+        summ["stage_ms"] = {
+            s: (round(float(np.median(c)), 3), round(float(c.max()), 3))
+            for s, c in zip(STAGES, rep.stage_ms.T)}
     if args.deadline_ms is not None:
         summ["degraded_fraction"] = round(rep.degraded_fraction, 4)
         summ["wave_cost_ms"] = round(rep.wave_cost_ms, 3)

@@ -1,11 +1,15 @@
 """Wave-scheduled serving (beyond-paper throughput layer)."""
+import glob
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.profiler import ProfileData
 
 from repro.core import brute_force, metrics, policies, search
-from repro.core.serving import WaveScheduler
+from repro.core.serving import STAGES, WaveScheduler
 
 pytestmark = pytest.mark.slow   # full serve loops: ~15s total
 
@@ -89,3 +93,102 @@ def test_wave_results_match_plain_search(tiny_index, tiny_corpus,
     r_plain = metrics.r_star_at_1(np.asarray(res.topk_ids),
                                   tiny_exact[1][:128, 0])
     assert abs(r_wave - r_plain) < 0.08
+
+
+def _col(stage):
+    return STAGES.index(stage)
+
+
+def _check_stage_rows(rep):
+    """Shape and the stages every wave runs; ``admit`` and
+    ``wait_admit`` positive exactly on the waves that dispatched
+    ``_admit``."""
+    ms = rep.stage_ms
+    assert ms.shape == (rep.waves, len(STAGES))
+    assert (ms >= 0).all()
+    for s in ("wait_advance", "harvest", "pin", "advance"):
+        assert (ms[:, _col(s)] > 0).all(), s
+    admitted = ms[:, _col("admit")] > 0
+    assert admitted.sum() == rep.admit_calls
+    assert np.array_equal(admitted, ms[:, _col("wait_admit")] > 0)
+    # no deadline, no rebuilder: those stages never run
+    assert (ms[:, [_col("ladder"), _col("rebuild")]] == 0).all()
+
+
+def test_stage_times_and_counters_of_a_plain_serve(tiny_index,
+                                                   tiny_corpus):
+    q = tiny_corpus.queries[:100]
+    ws = WaveScheduler(tiny_index, wave_size=32, chunk=4, k=10,
+                       n_probe=24, delta=3, phi=90.0)
+    rep = ws.serve(q)
+    _check_stage_rows(rep)
+    assert rep.empty_waves == 0
+    assert rep.admitted == len(rep.results) == 100
+    # 100 queries over 32 lanes: at least four admissions
+    assert 4 <= rep.admit_calls < rep.waves
+    # the spans change nothing the loop serves
+    ids = np.stack([rep.results[i] for i in range(100)])
+    probes = np.asarray([rep.probes[i] for i in range(100)])
+    ref = search(tiny_index, jnp.asarray(q),
+                 policies.patience(24, 3, 90.0, k=10))
+    np.testing.assert_array_equal(ids, np.asarray(ref.topk_ids))
+    np.testing.assert_array_equal(probes, np.asarray(ref.probes))
+
+
+class _HeldBack:
+    """Query rows of which the first ``n_now`` are due at once and the
+    rest once the scheduler's clock (one tick a reading) reaches
+    ``at``: the first lanes drain meanwhile, and the loop runs waves
+    with no active lane."""
+
+    def __init__(self, queries, n_now, at):
+        self._q, self._n_now, self._at = queries, n_now, at
+        self.shape = queries.shape
+        self.t = 0.0
+
+    def clock(self):
+        self.t += 1.0
+        return self.t
+
+    def __getitem__(self, sl):
+        stop = sl.stop if self.t >= self._at else min(sl.stop, self._n_now)
+        return self._q[sl.start: max(sl.start, stop)]
+
+
+def test_empty_waves_and_zero_row_admits_are_counted(tiny_index,
+                                                     tiny_corpus):
+    src = _HeldBack(tiny_corpus.queries[:48], 16, at=40.0)
+    ws = WaveScheduler(tiny_index, wave_size=16, chunk=4, k=10,
+                       n_probe=24, delta=3, phi=90.0, clock=src.clock)
+    rep = ws.serve(src)
+    assert len(rep.results) == rep.admitted == 48
+    assert rep.empty_waves > 0
+    _check_stage_rows(rep)
+    # while nothing is due, free lanes still dispatch _admit with no row
+    assert rep.admit_calls * 16 > rep.admitted
+
+
+def test_stage_spans_reach_the_profiler(tmp_path, tiny_index, tiny_corpus):
+    """Each stage is a ``serve.<stage>`` span on the trace's host plane:
+    one ``serve.advance`` per wave, and one ``serve.wait_advance`` per
+    pass of the loop (the waves and the last pass, which only waits
+    and harvests)."""
+    ws = WaveScheduler(tiny_index, wave_size=32, chunk=4, k=10,
+                       n_probe=24, delta=3, phi=90.0)
+    q = tiny_corpus.queries[:40]
+    ws.serve(q)                                  # compiled outside
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        rep = ws.serve(q)
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events]
+    assert names.count("serve.advance") == rep.waves
+    assert names.count("serve.wait_advance") == rep.waves + 1
+    assert names.count("serve.admit") == rep.admit_calls
+    assert {n for n in names if n.startswith("serve.")} \
+        == {f"serve.{s}" for s in STAGES if s not in ("ladder",
+                                                      "rebuild")}
