@@ -53,12 +53,16 @@ def _empty_state(w: int, d: int, n: int, k: int) -> LaneState:
         qid=jnp.full((w,), -1, jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("n_probe",))
+@functools.partial(jax.jit, static_argnames=("n_probe",),
+                   donate_argnames=("state",))
 def _admit(state: LaneState, centroids: jnp.ndarray, new_q: jnp.ndarray,
            new_qid: jnp.ndarray, n_probe: int) -> LaneState:
     """Fill empty lanes with the queries of ``new_q`` whose ``new_qid``
     is not -1 (vectorised).  Callers pad both to a fixed row count, so
-    one compiled program admits batches of every size."""
+    one compiled program admits batches of every size.  The valid rows
+    come first, and row j lands in the j-th free lane, so the host
+    knows the filled lanes without reading them back
+    (:func:`_admitted_lanes`)."""
     free = ~state.active                                  # (W,)
     # slot j of new_q goes to the j-th free lane
     free_rank = jnp.cumsum(free) - 1                      # rank among free
@@ -81,8 +85,16 @@ def _admit(state: LaneState, centroids: jnp.ndarray, new_q: jnp.ndarray,
         qid=jnp.where(take, jnp.take(new_qid, src), state.qid))
 
 
+def _admitted_lanes(active: np.ndarray, m: int) -> np.ndarray:
+    """The lanes :func:`_admit` fills with ``m`` valid rows, given the
+    host's copy of ``state.active``: the first ``m`` free ones."""
+    return np.flatnonzero(~active)[:m]
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("chunk", "k", "n_probe", "use_fused"))
+                   static_argnames=("chunk", "k", "n_probe", "phi",
+                                    "use_fused"),
+                   donate_argnames=("state",))
 def _advance(index: IVFIndex, state: LaneState,
              dview: Optional[DeltaView] = None,
              dead: Optional[jnp.ndarray] = None, *,
@@ -113,6 +125,10 @@ def _advance(index: IVFIndex, state: LaneState,
     tombstone lookup, scrubbing running top-k entries that were deleted
     after they were merged — required for mid-flight lanes that span an
     index version swap.
+
+    ``state`` is donated (its buffers hold the result) and ``phi`` is
+    static, so a dispatch neither allocates lane state nor copies a
+    scalar to the device.
     """
 
     if dead is not None:
@@ -206,12 +222,18 @@ _REASON_RANK = {"tightened_patience": 1, "capped_probes": 2,
 
 #: The serve loop's stages in loop order: the columns of
 #: ``ServeReport.stage_ms`` and, prefixed ``serve.``, the names of their
-#: profiler spans.  The ``WAIT_STAGES`` block on the device (a pull of
-#: ``state.active`` after ``_advance`` / ``_admit``); the rest is host
-#: work.
+#: profiler spans.  The ``WAIT_STAGES`` block on the device; the rest is
+#: host work.  ``wait_advance`` is the loop's one blocking pull a wave
+#: (:data:`_PULLED` of ``_advance``'s result).  ``wait_admit`` reads 0:
+#: the host knows the lanes ``_admit`` fills and waits for nothing.
 STAGES = ("wait_advance", "harvest", "pin", "ladder", "admit",
           "wait_admit", "advance", "rebuild")
 WAIT_STAGES = ("wait_advance", "wait_admit")
+
+#: The lane-state fields the host reads after each ``_advance``: copied
+#: asynchronously from its dispatch on, fetched together at the top of
+#: the next pass.
+_PULLED = ("active", "qid", "h", "topk_ids")
 
 
 class _StageClock:
@@ -273,6 +295,9 @@ class ServeReport:
     empty_waves: int = 0        # waves dispatched with no active lane
     admit_calls: int = 0        # _admit dispatches
     admitted: int = 0           # queries placed in a lane
+    # passes through the loop's one blocking read, waves + 1: counted
+    # at that read, so a second read elsewhere does not show here
+    host_pulls: int = 0
 
     @property
     def degraded_fraction(self) -> float:
@@ -408,28 +433,40 @@ class WaveScheduler:
         lane_steps = 0
         nq = queries.shape[0]
         prev_active = np.zeros(self.w, bool)
-        prev_state = state
         lane_admit = np.zeros(self.w, np.float64)   # admit timestamp, ms
         full_delta = jnp.full((self.w,), self.delta, jnp.int32)
         full_cap = jnp.full((self.w,), self.n, jnp.int32)
         wave_cost = 0.0                              # EMA of wave ms
+        last_read = stall = 0.0       # last read's time; rebuild ms since
         epoch_swaps = drain_waves = 0
         rebuild_ticks = rebuild_throttled = 0
-        empty_waves = admit_calls = admitted = 0
+        empty_waves = admit_calls = admitted = host_pulls = 0
         stage = _StageClock()
         self._pinned = None if self.registry is None \
             else self.registry.current()
         while True:
+            # the wave's one blocking read: everything below works on
+            # these host copies (``_advance`` leaves ``qid`` as it was)
             with stage("wait_advance"):
-                active = np.asarray(state.active)
+                active, qids, h_np, tid = jax.device_get(
+                    tuple(getattr(state, f) for f in _PULLED))
+            host_pulls += 1
             with stage("harvest"):
-                qids = np.asarray(state.qid)
                 now = self._now()
+                if waves:
+                    # the last wave's whole period, read to read: the
+                    # device time waited out in the read included, the
+                    # rebuild stall left out of the EMA the ladder
+                    # budgets against
+                    sample = now - last_read - stall
+                    wave_cost = sample if waves == 1 \
+                        else 0.5 * wave_cost + 0.5 * sample
+                last_read, stall = now, 0.0
                 # harvest exits: lanes that flipped active->inactive
                 for lane in np.nonzero(prev_active & ~active)[0]:
-                    qid = int(np.asarray(prev_state.qid)[lane])
-                    results[qid] = np.asarray(state.topk_ids)[lane]
-                    probes[qid] = int(np.asarray(state.h)[lane])
+                    qid = int(qids[lane])
+                    results[qid] = tid[lane]
+                    probes[qid] = int(h_np[lane])
                     latency[qid] = now - lane_admit[lane]
             # -- epoch-fenced version adoption ------------------------------
             with stage("pin"):
@@ -448,8 +485,6 @@ class WaveScheduler:
                     rungs = np.where(active, rungs, RUNG_NONE)
                     force = active & (rungs == RUNG_FORCE)
                     if force.any():
-                        h_np = np.asarray(state.h)
-                        tid = np.asarray(state.topk_ids)
                         for lane in np.nonzero(force)[0]:
                             qid = int(qids[lane])
                             results[qid] = tid[lane]
@@ -465,7 +500,6 @@ class WaveScheduler:
                                    if rungs[lane] >= RUNG_CAP
                                    else "tightened_patience")
                     if (rungs > RUNG_NONE).any():
-                        h_np = np.asarray(state.h)
                         afford = np.floor(
                             np.maximum(remaining, 0.0)
                             / max(wave_cost, 1e-9)).astype(np.int64) \
@@ -503,24 +537,22 @@ class WaveScheduler:
                             qpad[:m] = batch
                             ids = np.full(self.w, -1, np.int32)
                             ids[:m] = np.arange(next_q, next_q + m)
-                            before = active
                             state = _admit(state, self._centroids(),
                                            jnp.asarray(qpad),
                                            jnp.asarray(ids), self.n)
+                            filled = _admitted_lanes(active, m)
+                            active = active.copy()
+                            active[filled] = True
                         admit_calls += 1
                         next_q += m
-                        with stage("wait_admit"):
-                            newly = np.asarray(state.active) & ~before
-                        lane_admit[newly] = now
-                        admitted += int(newly.sum())
-            active = np.asarray(state.active)
+                        lane_admit[filled] = now
+                        admitted += m
             if not active.any() and next_q >= nq:
                 break
             empty_waves += int(not active.any())
             occ.append(active.mean())
             lane_steps += self.w * self.chunk
             prev_active = active
-            prev_state = state
             with stage("advance"):
                 index, dview, dead = self._version()
                 state = _advance(index, state, dview, dead,
@@ -528,22 +560,20 @@ class WaveScheduler:
                                  chunk=self.chunk, k=self.k,
                                  n_probe=self.n, phi=self.phi,
                                  use_fused=self.use_fused)
+                for f in _PULLED:
+                    getattr(state, f).copy_to_host_async()
             waves += 1
             if on_wave is not None:
                 on_wave(waves)
-            sample = self._now() - now
-            wave_cost = sample if waves == 1 \
-                else 0.5 * wave_cost + 0.5 * sample
             # -- background rebuild tick (throttled under pressure) ---------
-            # after the wave-cost sample so the stall never inflates
-            # the EMA the ladder budgets against
+            # timed, so the stall never inflates the wave-cost EMA
             if self.rebuilder is not None and self.rebuilder.active:
                 with stage("rebuild"):
+                    t0 = self._now()
                     throttle = False
                     if self.deadline_ms is not None:
-                        act_now = np.asarray(state.active)
-                        rem = (self.deadline_ms
-                               - (self._now() - lane_admit))[act_now]
+                        # the lanes this wave runs, from the host's copy
+                        rem = (self.deadline_ms - (t0 - lane_admit))[active]
                         throttle = self.ladder.throttle_rebuild(
                             rem, max(wave_cost, 1e-9))
                     if throttle:
@@ -551,6 +581,7 @@ class WaveScheduler:
                     else:
                         self.rebuilder.tick()
                         rebuild_ticks += 1
+                    stall = self._now() - t0
             stage.end_wave()
         return ServeReport(results, probes, waves,
                            float(np.mean(occ)) if occ else 0.0,
@@ -563,4 +594,5 @@ class WaveScheduler:
                            rebuild_ticks=rebuild_ticks,
                            rebuild_throttled=rebuild_throttled,
                            stage_ms=stage.ms(), empty_waves=empty_waves,
-                           admit_calls=admit_calls, admitted=admitted)
+                           admit_calls=admit_calls, admitted=admitted,
+                           host_pulls=host_pulls)

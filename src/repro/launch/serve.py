@@ -166,6 +166,7 @@ def main() -> None:
     summ["waves"] = rep.waves
     summ["empty_waves"] = rep.empty_waves
     summ["admit_calls"] = rep.admit_calls
+    summ["host_pulls"] = rep.host_pulls
     if rep.waves:       # per stage: median and max ms over waves
         summ["stage_ms"] = {
             s: (round(float(np.median(c)), 3), round(float(c.max()), 3))

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from jax.profiler import ProfileData
 
 from repro.core import brute_force, metrics, policies, search
+from repro.core import serving
 from repro.core.serving import STAGES, WaveScheduler
 
 pytestmark = pytest.mark.slow   # full serve loops: ~15s total
@@ -100,9 +101,9 @@ def _col(stage):
 
 
 def _check_stage_rows(rep):
-    """Shape and the stages every wave runs; ``admit`` and
-    ``wait_admit`` positive exactly on the waves that dispatched
-    ``_admit``."""
+    """Shape and the stages every wave runs; ``admit`` positive exactly
+    on the waves that dispatched ``_admit``; ``wait_admit`` 0 on every
+    wave, since the host knows the lanes ``_admit`` fills."""
     ms = rep.stage_ms
     assert ms.shape == (rep.waves, len(STAGES))
     assert (ms >= 0).all()
@@ -110,9 +111,9 @@ def _check_stage_rows(rep):
         assert (ms[:, _col(s)] > 0).all(), s
     admitted = ms[:, _col("admit")] > 0
     assert admitted.sum() == rep.admit_calls
-    assert np.array_equal(admitted, ms[:, _col("wait_admit")] > 0)
     # no deadline, no rebuilder: those stages never run
-    assert (ms[:, [_col("ladder"), _col("rebuild")]] == 0).all()
+    assert (ms[:, [_col("wait_admit"), _col("ladder"),
+                   _col("rebuild")]] == 0).all()
 
 
 def test_stage_times_and_counters_of_a_plain_serve(tiny_index,
@@ -190,5 +191,158 @@ def test_stage_spans_reach_the_profiler(tmp_path, tiny_index, tiny_corpus):
     assert names.count("serve.wait_advance") == rep.waves + 1
     assert names.count("serve.admit") == rep.admit_calls
     assert {n for n in names if n.startswith("serve.")} \
-        == {f"serve.{s}" for s in STAGES if s not in ("ladder",
+        == {f"serve.{s}" for s in STAGES if s not in ("wait_admit",
+                                                      "ladder",
                                                       "rebuild")}
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("share", [0.0, 0.25, 0.5, 1.0])
+def test_host_knows_the_lanes_admit_fills(tiny_index, seed, share):
+    """``_admitted_lanes`` on the host's ``active`` names exactly the
+    lanes the device's ``_admit`` fills, for any mask and any row count
+    from none to every free lane."""
+    w, d = 16, tiny_index.docs.shape[1]
+    rng = np.random.default_rng(seed)
+    active = rng.random(w) < 0.5
+    m = int(round(share * (~active).sum()))
+    state = serving._empty_state(w, d, 8, 10)._replace(
+        active=jnp.asarray(active),
+        qid=jnp.asarray(np.where(active, 1000 + np.arange(w), -1),
+                        jnp.int32))
+    ids = np.full(w, -1, np.int32)
+    ids[:m] = 7 + np.arange(m)
+    q = rng.normal(size=(w, d)).astype(np.float32)
+    out = serving._admit(state, tiny_index.centroids, jnp.asarray(q),
+                         jnp.asarray(ids), 8)
+    new_active, qid = jax.device_get((out.active, out.qid))
+    filled = serving._admitted_lanes(active, m)
+    assert len(filled) == m
+    np.testing.assert_array_equal(np.flatnonzero(new_active & ~active),
+                                  filled)
+    np.testing.assert_array_equal(qid[filled], ids[:m])
+
+
+def _ladder_scheduler(index):
+    """A deadline-ladder scheduler and its ``on_wave`` clock: 1 ms
+    waves, a 20 ms stall every fourth, forcing exits."""
+    from repro.runtime.chaos import SimClock
+    clock = SimClock()
+    ws = WaveScheduler(index, wave_size=16, chunk=1, k=10, n_probe=16,
+                       delta=3, phi=90.0, deadline_ms=5.0, clock=clock)
+
+    def tick(wave):
+        clock.advance(20.0 if wave % 4 == 0 else 1.0)
+    return ws, tick
+
+
+def _registry_scheduler(index):
+    from repro.index import IndexRegistry, LiveIndex, version_of
+    reg = IndexRegistry(version_of(LiveIndex(index, delta_cap=256)))
+    return WaveScheduler(index, wave_size=32, chunk=4, k=10, n_probe=24,
+                         delta=3, phi=90.0, registry=reg), None
+
+
+def _host_reads(monkeypatch):
+    """From here on, note each read of a ``jax.Array``'s value on the
+    host: through ``_value`` (``device_get``, ``int()``, ``bool()``,
+    ``tolist()``) and through the buffer protocol, which is how
+    ``np.asarray`` reads an array on the CPU."""
+    from jax._src.array import ArrayImpl
+    reads = []
+    value, buffer = ArrayImpl._value, ArrayImpl.__buffer__
+
+    def counted_value(self):
+        reads.append(self.shape)
+        return value.fget(self)
+
+    def counted_buffer(self, flags):
+        reads.append(self.shape)
+        return buffer(self, flags)
+    monkeypatch.setattr(ArrayImpl, "_value", property(counted_value))
+    monkeypatch.setattr(ArrayImpl, "__buffer__", counted_buffer)
+    return reads
+
+
+@pytest.mark.parametrize("how", ["plain", "ladder", "registry",
+                                 "planted"])
+def test_one_blocking_pull_a_wave(tiny_index, tiny_corpus, monkeypatch,
+                                  how):
+    """The loop reads the device once a pass, ``waves`` + 1 times, and
+    nothing else: every host read of an array during the serve is one of
+    the :data:`serving._PULLED` fields of that read.  The deadline
+    ladder's forced exits and the registry's pin included; and what it
+    reads is the state ``_advance`` hands back, fault planted in it as
+    the benchmark's checks plant one."""
+    q, mark = tiny_corpus.queries[:64], 999_999
+    if how == "ladder":
+        ws, on_wave = _ladder_scheduler(tiny_index)
+    elif how == "registry":
+        ws, on_wave = _registry_scheduler(tiny_index)
+    else:
+        ws, on_wave = WaveScheduler(tiny_index, wave_size=32, chunk=4,
+                                    k=10, n_probe=24, delta=3,
+                                    phi=90.0), None
+    if how == "planted":
+        advance = serving._advance
+
+        def altered(index, state, *a, **kw):
+            st = advance(index, state, *a, **kw)
+            return st._replace(topk_ids=st.topk_ids.at[:, 0].set(mark))
+        monkeypatch.setattr(serving, "_advance", altered)
+    get, gets = jax.device_get, []
+
+    def counted(x):
+        gets.append(1)
+        return get(x)
+    monkeypatch.setattr(jax, "device_get", counted)
+    reads = _host_reads(monkeypatch)
+    rep = ws.serve(q, on_wave=on_wave)
+    monkeypatch.undo()
+    assert set(rep.results) == set(range(64))
+    assert rep.host_pulls == len(gets) == rep.waves + 1
+    assert len(reads) == len(serving._PULLED) * rep.host_pulls
+    if how == "ladder":
+        assert "forced_exit" in rep.degraded.values()
+    if how == "planted":
+        assert all(ids[0] == mark for ids in rep.results.values())
+
+
+@pytest.mark.parametrize("deadline_ms", [5.0, 2.0])
+def test_ladder_budgets_the_time_spent_in_the_read(tiny_index, tiny_corpus,
+                                                   monkeypatch,
+                                                   deadline_ms):
+    """The wave cost the deadline ladder budgets against is the whole
+    wave, the device time the blocking read waits out included: a serve
+    whose 3 ms waves are all spent in the read degrades, sheds and
+    reports exactly as one whose waves are spent in host code, and no
+    query overshoots its budget by more than one wave."""
+    from repro.runtime.chaos import SimClock
+    wave_ms, q = 3.0, tiny_corpus.queries[:64]
+
+    def serve(in_read):
+        clock = SimClock()
+        ws = WaveScheduler(tiny_index, wave_size=16, chunk=1, k=10,
+                           n_probe=16, delta=3, phi=90.0,
+                           deadline_ms=deadline_ms, clock=clock)
+        get = jax.device_get
+
+        def slow_get(x):
+            clock.advance(wave_ms)
+            return get(x)
+        with monkeypatch.context() as mp:
+            if in_read:
+                mp.setattr(jax, "device_get", slow_get)
+            return ws.serve(q, on_wave=None if in_read
+                            else lambda w: clock.advance(wave_ms))
+    rep, host = serve(True), serve(False)
+    assert rep.wave_cost_ms == pytest.approx(wave_ms)
+    assert rep.degraded == host.degraded
+    assert rep.latency_ms == host.latency_ms
+    reasons = set(rep.degraded.values())
+    if deadline_ms > wave_ms:
+        assert "capped_probes" in reasons
+    else:
+        assert "shed" in reasons
+    for qid, lat in rep.latency_ms.items():
+        assert lat <= deadline_ms + wave_ms + 1e-9, qid
