@@ -28,7 +28,9 @@
   small device reads.
 * ``knee``: one set-up, then one open-loop window per rate.  The knee
   is the highest rate whose admission queue does not grow over the
-  window; the steady cell's rate is fixed at about 0.8 of it.
+  window; the steady cell's rate is fixed at about 0.8 of it.  Under
+  live traffic each window also applies its writes, so the delta
+  buffer fills from window to window.
 * ``limits``: per seed a full set-up and a window at the cell's own
   load, then the numbers the check compares, for the program and for
   the control (the reference in bf16 in the program's place).  The
@@ -94,15 +96,17 @@ def spread(a) -> None:
         del docs, dep
 
 
-def _setup(workload: str, seed: int, n_pool: int = 0):
+def _setup(workload: str, seed: int, n_pool: int = 0,
+           seconds: float = 5.0):
     """The cell's configuration and traffic, and a warmed deployment
-    with at least ``n_pool`` queries; returns (cfg, tr, dep, rate)."""
+    with at least ``n_pool`` queries (a live one planned for a window
+    of ``seconds``); returns (cfg, tr, dep, rate)."""
     bm = harness.benchmark()
     cell = harness.cell_of(bm, workload)
     cfg = harness.load("configs", cell["config"])
     tr = harness.load("traffic", cell["traffic"])
-    dep = harness.Deployment(cfg, seed, max(n_pool, tr["pool_queries"]),
-                             tr["queries"])
+    dep = harness.deployment(cfg, seed, tr, seconds,
+                             max(n_pool, tr["pool_queries"]))
     return cfg, tr, dep, harness.warm_up_rate(dep, tr)
 
 
@@ -118,7 +122,7 @@ def _window(dep, tr, seconds: float, seed: int, rate: float):
 def knee(a) -> None:
     rates = [float(x) for x in a.rates.split(",")]
     n_pool = int(max(rates) * a.seconds * 1.05) + 4096
-    _, tr, dep, _ = _setup(a.workload, a.seed, n_pool)
+    _, tr, dep, _ = _setup(a.workload, a.seed, n_pool, a.seconds)
     for r in rates:
         arr = {"kind": "poisson", "phases": [{"seconds": 1.0,
                                               "rate_qps": r}]}
@@ -139,13 +143,12 @@ def knee(a) -> None:
 
 def limits(a) -> None:
     for seed in (int(x) for x in a.seeds.split(",")):
-        _, tr, dep, rate = _setup(a.workload, seed)
+        _, tr, dep, rate = _setup(a.workload, seed, seconds=a.seconds)
         w = _window(dep, tr, a.seconds, seed, rate)
-        held = dep.release()
-        numbers = harness.check(dep, held, w.report, w.attempted, tr, seed,
-                                control=True)
+        numbers = harness.check_window(dep, w.report, w.attempted, tr,
+                                       seed, control=True)
         emit(mode="limits", workload=a.workload, seed=seed, **numbers)
-        del dep, held
+        del dep
 
 
 def length(a) -> None:
@@ -155,7 +158,7 @@ def length(a) -> None:
     lengths = [float(x) for x in a.lengths.split(",")]
     metrics = harness.cell_metrics(harness.benchmark(), a.workload, False)
     for seed in (int(x) for x in a.seeds.split(",")):
-        _, tr, dep, rate = _setup(a.workload, seed)
+        _, tr, dep, rate = _setup(a.workload, seed, seconds=sum(lengths))
         for secs in lengths:
             w = _window(dep, tr, secs, seed, rate)
             vals = {m["name"]: harness.reader(m["name"])(w)

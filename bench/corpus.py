@@ -88,6 +88,27 @@ def make_queries(key, docs, centers, spread, easy_noise, *, n_queries: int,
     return q[jax.random.permutation(ks[5], n_queries)]
 
 
+@functools.partial(jax.jit, static_argnames=("n_docs", "n_queries",
+                                             "n_hard"))
+def _query_sources(key, *, n_docs: int, n_queries: int, n_hard: int):
+    n_easy = n_queries - n_hard
+    ks = jax.random.split(key, 6)
+    src = jax.random.randint(ks[0], (n_easy,), 0, n_docs)
+    src = jnp.concatenate([src, jnp.full((n_hard,), -1, src.dtype)])
+    return src[jax.random.permutation(ks[5], n_queries)]
+
+
+def query_sources(seed: int, cfg: dict, n_queries: int,
+                  query_mix: dict) -> np.ndarray:
+    """(n_queries,) int32: the doc each easy query of :func:`generate`'s
+    pool is a noisy copy of, -1 for a hard query.  The same draws from
+    the same key as :func:`make_queries`."""
+    return np.asarray(_query_sources(
+        jax.random.fold_in(base_key(seed), 1), n_docs=cfg["n_docs"],
+        n_queries=n_queries,
+        n_hard=int(round(n_queries * query_mix["hard_frac"]))))
+
+
 @jax.jit
 def fingerprint(rows: jnp.ndarray) -> jnp.ndarray:
     """(n, d) f32 -> (n,) uint32: the bits of each row times fixed odd

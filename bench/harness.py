@@ -7,6 +7,8 @@ Everything that belongs to one cell is found by name:
 * ``bench/configs/<config>.json`` holds the deployment's sizes, the
   exit policy, the generator's settings and the check's limits;
 * ``bench/traffic/<traffic>.json`` holds the arrivals and query mix;
+  one with ``"kind": "live"`` also holds writes, served through the
+  program's live index (``bench/live.py``), and limits of its own;
 * ``bench/metrics/<metric>.py`` holds a ``read(window)`` that returns
   the metric's value from a :class:`Window`, or ``None`` where it
   finds nothing to read.
@@ -140,6 +142,7 @@ class Window:
     admit_ms: Optional[np.ndarray] = None    # open loop only
     trace: Optional[trace.Summary] = None    # traced runs only
     needed_bytes: Optional[float] = None     # traced runs only
+    live: Optional[dict] = None              # live traffic only
 
     def completed(self) -> np.ndarray:
         return np.asarray(sorted(self.report.results), np.int64)
@@ -190,22 +193,44 @@ class Deployment:
                              delta=c["patience_delta"],
                              phi=c["patience_phi"], clock=clock)
 
+    def wave_marks(self, traced: bool, source, seconds: float,
+                   seed: int) -> "WaveMarks":
+        """``serve``'s ``on_wave`` for a window."""
+        return WaveMarks(traced)
+
     def release(self) -> dict:
         """Take what the check needs from the index to the host, then
         drop the program's hold on the device."""
-        ix = self.index
-        held = {"ids": np.asarray(ix.doc_ids),
-                "row_prints": np.asarray(corpus.fingerprint(ix.docs)),
-                "offsets": np.asarray(ix.cluster_offsets),
-                "sizes": np.asarray(ix.cluster_sizes),
-                "centroids": np.asarray(ix.centroids),
-                "dtypes": {"docs": str(ix.docs.dtype),
-                           "centroids": str(ix.centroids.dtype)},
-                "list_pad": ix.list_pad}
+        held = held_of(self.index)
         self.index = None
-        del ix
         gc.collect()
         return held
+
+
+def held_of(ix) -> dict:
+    """What the check takes from an index, as host arrays."""
+    return {"ids": np.asarray(ix.doc_ids),
+            "row_prints": np.asarray(corpus.fingerprint(ix.docs)),
+            "offsets": np.asarray(ix.cluster_offsets),
+            "sizes": np.asarray(ix.cluster_sizes),
+            "centroids": np.asarray(ix.centroids),
+            "dtypes": {"docs": str(ix.docs.dtype),
+                       "centroids": str(ix.centroids.dtype)},
+            "list_pad": ix.list_pad}
+
+
+def is_live(tr: dict) -> bool:
+    return tr.get("kind") == "live"
+
+
+def deployment(cfg: dict, seed: int, tr: dict, seconds: float,
+               n_pool: Optional[int] = None) -> Deployment:
+    """The configuration made real for this traffic."""
+    if is_live(tr):
+        from bench import live
+        return live.LiveDeployment(cfg, seed, tr, seconds, n_pool)
+    return Deployment(cfg, seed, n_pool or tr["pool_queries"],
+                      tr["queries"])
 
 
 def warm_up(dep: Deployment, n: int) -> float:
@@ -284,7 +309,8 @@ def measure(dep: Deployment, tr: dict, seconds: float, seed: int,
         source = traffic.OpenLoopSource(dep.queries[:due.shape[0]], due)
         clock = source.clock
     ws = dep.scheduler(clock)
-    marks = WaveMarks(traced) if marks is None else marks
+    marks = dep.wave_marks(traced, source, seconds, seed) if marks is None \
+        else marks
     tdir = tempfile.mkdtemp(prefix="bench-trace-") if traced else None
     try:
         if traced:
@@ -323,6 +349,20 @@ def measure(dep: Deployment, tr: dict, seconds: float, seed: int,
     return rep, wall, source.shape[0], due, admit, summary
 
 
+def draw_sample(done: np.ndarray, probes: np.ndarray, tr: dict,
+                seed: int) -> np.ndarray:
+    """The check's sample of the completed queries ``done`` (ascending,
+    ``probes`` each): the traffic's ``check_longest`` of most probes and
+    the rest of ``check_queries`` drawn from the seed, ascending."""
+    rng = np.random.default_rng([seed % (1 << 64), 3])
+    n_long = min(tr["check_longest"], done.size)
+    longest = done[np.argsort(-probes, kind="stable")[:n_long]]
+    rest = np.setdiff1d(done, longest)
+    n_rand = min(tr["check_queries"] - n_long, rest.size)
+    return np.sort(np.concatenate(
+        [longest, rng.choice(rest, n_rand, replace=False)]))
+
+
 def check(dep: Deployment, held: dict, rep: ServeReport, attempted: int,
           tr: dict, seed: int, *, control: bool = False) -> dict:
     """Compare what the window served with the plain reference.
@@ -340,13 +380,7 @@ def check(dep: Deployment, held: dict, rep: ServeReport, attempted: int,
                                   held["list_pad"])
     done = np.asarray(sorted(rep.results), np.int64)
     probes = np.asarray([rep.probes[i] for i in done])
-    rng = np.random.default_rng([seed % (1 << 64), 3])
-    n_long = min(tr["check_longest"], done.size)
-    longest = done[np.argsort(-probes, kind="stable")[:n_long]]
-    rest = np.setdiff1d(done, longest)
-    n_rand = min(tr["check_queries"] - n_long, rest.size)
-    sample = np.sort(np.concatenate(
-        [longest, rng.choice(rest, n_rand, replace=False)]))
+    sample = draw_sample(done, probes, tr, seed)
     ids = np.stack([rep.results[i] for i in sample])
     prb = np.asarray([rep.probes[i] for i in sample])
     q = dep.queries[sample]
@@ -395,6 +429,17 @@ def check(dep: Deployment, held: dict, rep: ServeReport, attempted: int,
     return numbers
 
 
+def check_window(dep: Deployment, rep: ServeReport, attempted: int,
+                 tr: dict, seed: int, *, control: bool = False) -> dict:
+    """Release the program's state and compare what the window served
+    with the plain reference (a live run: see ``bench/live.py``)."""
+    if is_live(tr):
+        from bench import live
+        return live.check(dep, rep, attempted, tr, seed, control=control)
+    held = dep.release()
+    return check(dep, held, rep, attempted, tr, seed, control=control)
+
+
 def run(workload: str, seed: int, seconds: float, traced: bool, *,
         root: Path = ROOT, platform: str = "tpu",
         t0: Optional[float] = None) -> dict:
@@ -412,7 +457,7 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
 
     win_s = min(seconds, tr["trace_seconds"]) if traced else seconds
     with CompileCount() as cc:
-        dep = Deployment(cfg, seed, tr["pool_queries"], tr["queries"])
+        dep = deployment(cfg, seed, tr, win_s)
         t = time.perf_counter()
         rate = warm_up_rate(dep, tr)
         n_window = window_queries(dep, tr, rate, win_s)
@@ -447,6 +492,8 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
             np.asarray(dep.index.cluster_sizes), cfg["dim"])
     w = Window(cfg, rep, wall, attempted, setup_s, in_use,
                dev.device_kind, due, admit, summary, needed)
+    if is_live(tr):
+        w.live = dep.window_stats()
     metrics = {}
     for m in cell_metrics(bm, workload, traced):
         v = reader(m["name"], root)(w)
@@ -461,9 +508,8 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
              max_ms=float(lat.max()),
              queue_p99_ms=float(np.percentile(w.queue_ms(), 99)))
 
-    held = dep.release()
-    numbers = check(dep, held, rep, attempted, tr, seed)
-    limits = cfg["limits"]
+    numbers = check_window(dep, rep, attempted, tr, seed)
+    limits = dict(cfg["limits"], **tr.get("limits", {}))
     correct = all(numbers[k] <= limits[k] for k in limits)
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(numbers["failed"]), "metrics": metrics,

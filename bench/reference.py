@@ -19,6 +19,12 @@ configuration by its own arithmetic:
   and each doc's list against its nearest centroid
   (``assign_excess``), each as a share of the quantization error.
 
+A live index (``bench/live.py``) is searched the same way over the
+union of the built lists and the added docs, each added doc placed in
+its nearest centroid's list by this module's own f32 arithmetic
+(:func:`nearest_lists`, :func:`with_members`); each query sees only
+the ids live in the version it was served on.
+
 Precision: by default every product is f32 (``Precision.HIGHEST``),
 as the configuration states.  ``lowp=True`` rounds queries, docs and
 centroids to bfloat16 and accumulates in f32: the control, which the
@@ -27,7 +33,7 @@ comparison has to refuse.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +115,45 @@ def partition_of(centroids: np.ndarray, ids: np.ndarray,
     table = np.where(lane[None, :] < sizes[:, None], ids[rows], -1)
     return Partition(np.asarray(centroids, np.float32),
                      table.astype(np.int32))
+
+
+def with_members(part: Partition, ids: np.ndarray, lists: np.ndarray,
+                 align: int = 128) -> Partition:
+    """``part`` with ``ids`` appended to ``lists`` (one list per id,
+    after the list's own members, in the order given); the table grows
+    to a multiple of ``align`` that holds the longest list."""
+    n_main = (part.table >= 0).sum(1)
+    add = np.bincount(lists, minlength=n_main.size)
+    width = max(part.table.shape[1], int((n_main + add).max(initial=0)))
+    width = -(-width // align) * align
+    table = np.full((n_main.size, width), -1, np.int32)
+    table[:, :part.table.shape[1]] = part.table
+    order = np.argsort(lists, kind="stable")
+    ls = lists[order]
+    first = np.searchsorted(ls, ls)                # first of its list
+    table[ls, n_main[ls] + np.arange(ls.size) - first] = ids[order]
+    return Partition(part.centroids, table)
+
+
+@jax.jit
+def _nearest(x, centroids):
+    s = _dot(x, centroids, False, "bd,cd->bc") \
+        - 0.5 * jnp.sum(centroids * centroids, 1)[None, :]
+    return jnp.argmax(s, 1).astype(jnp.int32)
+
+
+def nearest_lists(vecs: np.ndarray, centroids: np.ndarray,
+                  block: int = 1024) -> np.ndarray:
+    """Each vector's nearest centroid in squared L2, in f32 at
+    ``HIGHEST`` (argmax of x.c - |c|^2 / 2), in blocks of one shape."""
+    c = jnp.asarray(np.asarray(centroids, np.float32))
+    out = []
+    for lo in range(0, vecs.shape[0], block):
+        x = np.zeros((block, vecs.shape[1]), np.float32)
+        m = min(block, vecs.shape[0] - lo)
+        x[:m] = vecs[lo: lo + m]
+        out.append(np.asarray(_nearest(jnp.asarray(x), c))[:m])
+    return np.concatenate(out) if out else np.zeros(0, np.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -195,12 +240,29 @@ def rank_clusters(queries, centroids, *, n_probe: int, lowp: bool = False):
     return jax.lax.top_k(sims, n_probe)[1].astype(jnp.int32)
 
 
+def _rows(docs, extra, ids):
+    """The vectors of ``ids``: rows of ``docs``, and from
+    ``docs.shape[0]`` on rows of ``extra``."""
+    if extra is None:
+        return docs[jnp.maximum(ids, 0)]
+    n = docs.shape[0]
+    main = docs[jnp.clip(ids, 0, n - 1)]
+    added = extra[jnp.clip(ids - n, 0, extra.shape[0] - 1)]
+    return jnp.where((ids >= n)[..., None], added, main)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "n_probe", "delta",
                                              "lowp"))
-def patience_search(queries, centroids, table, docs, phi, *, k: int,
+def patience_search(queries, centroids, table, docs, phi, extra=None,
+                    born=None, death=None, ver=None, *, k: int,
                     n_probe: int, delta: int, lowp: bool = False):
     """Per-probe patience search.  Returns (B, k) ids in descending
-    score, (B, k) scores and (B,) probes taken."""
+    score, (B, k) scores and (B,) probes taken.
+
+    For a live index: ``extra`` holds the vectors of the ids from
+    ``docs.shape[0]`` on (docs added after the build), and query b
+    searches an id only where ``born[id] <= ver[b] < death[id]``: the
+    versions of the index in which that id was live."""
     b = queries.shape[0]
     rank = rank_clusters(queries, centroids, n_probe=n_probe, lowp=lowp)
 
@@ -211,7 +273,11 @@ def patience_search(queries, centroids, table, docs, phi, *, k: int,
     def body(s):
         h, ts, ti, ctr, active, probes = s
         ids = table[rank[:, h]]                                # (B, L)
-        vecs = docs[jnp.maximum(ids, 0)]                       # (B, L, d)
+        if born is not None:
+            i = jnp.maximum(ids, 0)
+            v = ver[:, None]
+            ids = jnp.where((born[i] <= v) & (death[i] > v), ids, -1)
+        vecs = _rows(docs, extra, ids)                         # (B, L, d)
         sc = _dot(vecs, queries, lowp, "bld,bd->bl")
         sc = jnp.where(ids >= 0, sc, -jnp.inf)
         ns, pos = jax.lax.top_k(jnp.concatenate([ts, sc], 1), k)
@@ -260,10 +326,13 @@ def exact_topk(queries, docs, *, k: int, block: int = 65536):
 
 
 def search_blocks(queries: np.ndarray, part: Partition, docs_dev, cfg: dict,
-                  *, lowp: bool = False, q_block: int = 128
+                  *, lowp: bool = False, q_block: int = 128, extra=None,
+                  born=None, death=None, ver: Optional[np.ndarray] = None
                   ) -> Tuple[np.ndarray, np.ndarray]:
     """Reference ids and probes for ``queries`` (host), in query blocks
-    of one compiled shape."""
+    of one compiled shape.  ``extra``/``born``/``death`` (device) and
+    ``ver`` (host, one per query): a live index, as
+    :func:`patience_search` takes it."""
     n = queries.shape[0]
     cen = jnp.asarray(part.centroids)
     table = jnp.asarray(part.table)
@@ -273,10 +342,16 @@ def search_blocks(queries: np.ndarray, part: Partition, docs_dev, cfg: dict,
         q = np.zeros((q_block, queries.shape[1]), np.float32)
         m = min(q_block, n - lo)
         q[:m] = queries[lo: lo + m]
+        v = None
+        if ver is not None:
+            v = np.zeros(q_block, np.int32)
+            v[:m] = ver[lo: lo + m]
+            v = jnp.asarray(v)
         i, _, p = patience_search(
             jnp.asarray(q), cen, table, docs_dev,
-            jnp.float32(cfg["patience_phi"]), k=cfg["k"], n_probe=n_probe,
-            delta=cfg["patience_delta"], lowp=lowp)
+            jnp.float32(cfg["patience_phi"]), extra, born, death, v,
+            k=cfg["k"], n_probe=n_probe, delta=cfg["patience_delta"],
+            lowp=lowp)
         ids.append(np.asarray(i)[:m])
         probes.append(np.asarray(p)[:m])
     return np.concatenate(ids), np.concatenate(probes)

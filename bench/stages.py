@@ -32,8 +32,8 @@ def host_ms(w) -> Optional[float]:
 
 
 def wait_ms(w) -> Optional[float]:
-    """Median over waves of the ms spent waiting on ``_advance`` and
-    ``_admit``."""
+    """Median over waves of the ms of the stages that wait on the
+    device: the loop's one blocking read of ``_advance``'s result."""
     s = _stage_sums(w, waits=True)
     return None if s is None else float(np.median(s))
 

@@ -9,9 +9,13 @@ the tests check it on small recorded or hand-made traces.
 * Device operations are the events of the ``XLA Ops`` line of every
   ``/device:`` plane; programs are those of its ``XLA Modules`` line.
 * Host spans are the benchmark's own ``TraceAnnotation``s, whose names
-  start with ``bench.``; the window is the ``bench.window`` span.
+  start with ``bench.``, and the serve loop's stages, ``serve.*``; the
+  window is the ``bench.window`` span.
 * Busy time is the union of a device's operation intervals inside the
   window, averaged over the devices; idle is the rest of the window.
+  Each idle gap is cut at the edges of the host spans inside it, and
+  each piece is put down to the innermost span around it: the stage
+  the host was in while the device waited.
 """
 from __future__ import annotations
 
@@ -24,7 +28,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]          # (start_ns, end_ns)
 
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "serve.")
 WINDOW_SPAN = "bench.window"
 
 
@@ -51,7 +55,7 @@ class Event:
 class Trace:
     ops: Dict[str, List[Event]]        # device plane -> its operations
     modules: Dict[str, List[Event]]    # device plane -> its programs
-    spans: List[Event]                 # host spans named bench.*
+    spans: List[Event]                 # host spans named bench.*, serve.*
 
 
 def _events(line) -> List[Event]:
@@ -142,8 +146,8 @@ def idle_gaps(intervals: Iterable[Interval], lo: float, hi: float
 
 class SpanIndex:
     """Innermost host span around a time.  Spans of one name do not
-    overlap each other (each is a phase, a window or a wave), so one
-    bisection per name finds every candidate."""
+    overlap each other (each is a phase, a window, a wave or a stage),
+    so one bisection per name finds every candidate."""
 
     def __init__(self, spans: Sequence[Event]):
         by_name: Dict[str, List[Event]] = defaultdict(list)
@@ -153,6 +157,16 @@ class SpanIndex:
                          for n, v in by_name.items()}
         self._starts = {n: [e.start for e in v]
                         for n, v in self._by_name.items()}
+        self._edges = sorted({t for sp in spans for t in (sp.start, sp.end)})
+
+    def pieces(self, lo: float, hi: float) -> List[Tuple[str, float]]:
+        """[lo, hi) cut at every span edge inside it: (label, length)
+        of each piece, labelled at its midpoint."""
+        i = bisect.bisect_right(self._edges, lo)
+        j = bisect.bisect_left(self._edges, hi)
+        cuts = [lo] + self._edges[i:j] + [hi]
+        return [(self.label(0.5 * (a + b)), b - a)
+                for a, b in zip(cuts, cuts[1:]) if b > a]
 
     def label(self, t: float) -> str:
         best: Optional[Event] = None
@@ -229,8 +243,8 @@ def summarize(trace: Trace) -> Summary:
             if d > 0:
                 op_s[lab] += d / 1e9
         for g0, g1 in idle_gaps(iv, lo, hi):
-            gap_s[spans.label(0.5 * (g0 + g1))] += \
-                (g1 - g0) / 1e9 / len(trace.ops)
+            for lab, ns in spans.pieces(g0, g1):
+                gap_s[lab] += ns / 1e9 / len(trace.ops)
     for evs in trace.modules.values():
         for e in evs:
             d = min(e.end, hi) - max(e.start, lo)

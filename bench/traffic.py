@@ -71,6 +71,21 @@ class OpenLoopSource:
         self._last = (time.perf_counter() - self._t0) * 1000.0
         return self._last
 
+    @property
+    def due_ms(self) -> np.ndarray:
+        return self._due
+
+    @property
+    def last_ms(self) -> float:
+        """The scheduler's latest clock reading: the time its current
+        pass admits at."""
+        return self._last
+
+    def elapsed_ms(self) -> float:
+        """Time since :meth:`start`, read without moving what the next
+        slice admits."""
+        return (time.perf_counter() - self._t0) * 1000.0
+
     def __len__(self) -> int:
         return self.shape[0]
 

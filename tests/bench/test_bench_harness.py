@@ -110,6 +110,13 @@ def test_metrics_apply_to_the_cells_they_name():
                                                      False)}
     assert steady == {"setup_s", "p95_ms", "hbm_bytes_per_doc"}
     assert batch == {"setup_s", "qps", "hbm_bytes_per_doc"}
+    # the 768-d full queue: judged on qps, with the batch metrics
+    got = {m["name"] for m in harness.cell_metrics(bm, "star768-batch",
+                                                   False)}
+    assert got == {"setup_s", "qps", "hbm_bytes_per_doc"}
+    layers = harness.cell_metrics(bm, "star768-batch", True)
+    assert layers and all(m["name"].endswith(".batch")
+                          and m["moves"] == "qps" for m in layers)
     for m in bm["per_layer"] + bm["end_to_end"]:
         assert (REPO / "bench" / "metrics" / f"{m['name']}.py").exists()
 

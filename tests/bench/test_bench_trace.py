@@ -88,3 +88,66 @@ def test_span_index_prefers_the_innermost_span():
     assert ix.label(25) == "bench.window"
     assert ix.label(59) == "bench.wave"
     assert ix.label(150) == "outside bench spans"
+
+
+@pytest.fixture(scope="module")
+def recorded_stages(tmp_path_factory):
+    """A real trace whose waves hold the serve loop's stage spans."""
+    d = tmp_path_factory.mktemp("xplane_stages")
+    f = jax.jit(lambda x: x * 3 - 1)
+    x = jax.numpy.ones((64,))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(d))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        for _ in range(2):
+            with jax.profiler.TraceAnnotation("bench.wave"):
+                with jax.profiler.TraceAnnotation("serve.wait_advance"):
+                    f(x).block_until_ready()
+                with jax.profiler.TraceAnnotation("serve.admit"):
+                    f(x).block_until_ready()
+                with jax.profiler.TraceAnnotation("bench.write"):
+                    f(x).block_until_ready()
+    jax.profiler.stop_trace()
+    return trace.load(str(d))
+
+
+def test_load_collects_the_serve_stages(recorded_stages):
+    names = sorted(s.name for s in recorded_stages.spans)
+    assert names == ["bench.wave"] * 2 + ["bench.window"] \
+        + ["bench.write"] * 2 + ["serve.admit"] * 2 \
+        + ["serve.wait_advance"] * 2
+
+
+@pytest.mark.parametrize("reader", ["idle_share.steady", "idle_share.batch",
+                                    "idle_share.live"])
+def test_stage_spans_leave_busy_and_idle_unchanged(recorded_stages, reader):
+    """Labelling idle gaps by the serve loop's stages moves idle time
+    between labels only: on one recorded event list, with and without
+    the ``serve.*`` spans, the window, the busy time, the idle total and
+    every ``idle_share.*`` read the same."""
+    import types
+    from bench import harness
+    lo, hi = trace.window(recorded_stages)
+    span = hi - lo
+    stage = [s for s in recorded_stages.spans if s.name.startswith("serve.")]
+    ops = [trace.Event("%ivf_scan_merge.1 = () custom-call()",
+                       s.start + 0.1 * (s.end - s.start),
+                       s.start + 0.6 * (s.end - s.start)) for s in stage]
+    ops.append(trace.Event("%fusion.2 = () fusion()", lo + 0.9 * span,
+                           lo + 0.95 * span))
+    bench_only = [s for s in recorded_stages.spans
+                  if s.name.startswith("bench.")]
+    sums = [trace.summarize(trace.Trace({"/device:TPU:0": ops},
+                                        {"/device:TPU:0": []}, sp))
+            for sp in (recorded_stages.spans, bench_only)]
+    read = harness.reader(reader)
+    with_stages, without = sums
+    assert with_stages.window_s == without.window_s
+    assert with_stages.busy_s == without.busy_s
+    assert with_stages.idle_share == without.idle_share
+    assert sum(with_stages.gap_s.values()) == pytest.approx(
+        sum(without.gap_s.values()))
+    assert read(types.SimpleNamespace(trace=with_stages)) == \
+        read(types.SimpleNamespace(trace=without))
+    assert {"serve.wait_advance", "serve.admit"} <= set(with_stages.gap_s)
+    assert not any(k.startswith("serve.") for k in without.gap_s)
